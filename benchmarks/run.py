@@ -90,6 +90,8 @@ def main() -> None:
                          "that support it)")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (ablations, accuracy_proxy, attn_latency,
                             complexity, decode_latency, niah, roofline_table,
                             serving_throughput, ttft)

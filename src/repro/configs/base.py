@@ -175,7 +175,6 @@ class ModelConfig:
     source: str = ""
     # ---- runtime ----
     quoka: QuokaConfig = field(default_factory=QuokaConfig)
-    use_pallas: bool = False       # True on real TPU; CPU runs use XLA path
     remat: bool = False            # activation checkpointing in the stack
     max_seq_len: int = 131_072
 

@@ -152,7 +152,9 @@ def quoka_scores(q: jax.Array, k: jax.Array, valid: jax.Array,
 
     if cfg.scoring == "cosine" and cfg.query_agg == "max":
         from repro.kernels import ops as kops
-        backend = kops.resolve_backend(cfg=cfg)
+        # a concrete cfg.backend is the model's resolved choice: an exported
+        # REPRO_BACKEND must not switch scoring away from it
+        backend = kops.resolve_backend(cfg.backend)
         # facade path: the fused Pallas kernel (Q̄ VMEM-resident, K streamed
         # once) or its XLA twin with FUSED key normalisation (§Perf A1 —
         # scores divided by per-key norms so no normalised fp32 copy of the
@@ -267,7 +269,6 @@ def tp_plan_candidates(qs: jax.Array, k: jax.Array, key_pos: jax.Array,
     granularity 1, (b, budget//g) block ids at granularity g > 1; -1 marks
     padding.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import ops as kops
@@ -282,7 +283,7 @@ def tp_plan_candidates(qs: jax.Array, k: jax.Array, key_pos: jax.Array,
     nb = budget // g                                      # plan slots
     tl = t // msize
     n_cand = min(nb, tl // g)                             # per-shard slots
-    backend = kops.resolve_backend(cfg=cfg)
+    backend = kops.resolve_backend(cfg.backend)        # as in quoka_scores
     keep_first = cfg.keep_first
     proj = score_proj(cfg, d)
 
@@ -327,9 +328,9 @@ def tp_plan_candidates(qs: jax.Array, k: jax.Array, key_pos: jax.Array,
         return jnp.where(good, top_i, -1)
 
     out_spec = P(b_ax, None, None) if g == 1 else P(b_ax, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_ax, None, None, None), P(b_ax, None, None, None),
                   P(b_ax, None), P(b_ax, None)),
         out_specs=out_spec,
-        check_rep=False)(qbar, k, key_pos, valid)
+        check_vma=False)(qbar, k, key_pos, valid)

@@ -14,29 +14,24 @@ the remaining keys are causal with respect to chunk-local indices:
 
 With boundary=0 this is plain causal attention (training); with
 causal=False it is a dense cross-attention.
+
+Mosaic tiling: every block's last two dims are multiples of (8, 128) or
+equal the array's own.  Key validity therefore travels as int32 with a
+unit second-minor axis, ``(b, h_kv, 1, tk)`` in ``(1, 1, 1, block_k)``
+blocks, and the row statistics m / l are ``(block_q, 1)`` columns — no
+bool and no 1-D vector reaches the kernel.
 """
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
-
-try:  # TPU compiler params are optional on CPU/interpret
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape, dtype: pltpu.VMEM(shape, dtype)
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = None
-    _COMPILER_PARAMS = None
 
 NEG_INF = -1e30
 
@@ -62,18 +57,18 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
     iq = pl.program_id(2)
     qi = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kj = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = valid_ref[0, 0][None, :]
+    mask = valid_ref[0, 0] != 0                           # (1, bk)
     if causal:
         mask = mask & ((kj < boundary) | ((kj - boundary) <= qi))
 
     m_prev = m_ref[...]
     l_prev = l_ref[...]
     s = jnp.where(mask, s, NEG_INF)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)  # explicit re-mask
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))  # (bq, 1)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # explicit re-mask
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_prev + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -81,9 +76,8 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
     def _done():
         l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = jnp.where(
-            (l > 0)[:, None], acc_ref[...] / safe[:, None], 0.0
-        ).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(l > 0, acc_ref[...] / safe, 0.0
+                                ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -125,13 +119,14 @@ def flash_attention_bhtd(q, k, v, k_valid=None, *, causal: bool = True,
         k_valid = jnp.ones((b, h_kv, tk), bool)
     elif k_valid.ndim == 2:
         k_valid = jnp.broadcast_to(k_valid[:, None, :], (b, h_kv, tk))
+    k_valid = k_valid.astype(jnp.int32)[:, :, None, :]    # (b, h_kv, 1, tk)
     if pq or pd:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, pd)))
     if pk or pd:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, pd)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, pd)))
     if pk:
-        k_valid = jnp.pad(k_valid, ((0, 0), (0, 0), (0, pk)))
+        k_valid = jnp.pad(k_valid, ((0, 0), (0, 0), (0, 0), (0, pk)))
     tq_p, tk_p, d_p = tq + pq, tk + pk, d + pd
     n_k = tk_p // block_k
     grid = (b, h, tq_p // block_q, n_k)
@@ -140,10 +135,6 @@ def flash_attention_bhtd(q, k, v, k_valid=None, *, causal: bool = True,
         _kernel, scale=scale, causal=causal, boundary=boundary,
         block_q=block_q, block_k=block_k, n_k=n_k)
 
-    kwargs = {}
-    if not interpret and _COMPILER_PARAMS is not None:  # pragma: no cover
-        kwargs["compiler_params"] = _COMPILER_PARAMS(
-            dimension_semantics=semantics)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -154,18 +145,18 @@ def flash_attention_bhtd(q, k, v, k_valid=None, *, causal: bool = True,
                          lambda bi, hi, iq, ik, g=g: (bi, hi // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, d_p),
                          lambda bi, hi, iq, ik, g=g: (bi, hi // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, iq, ik, g=g: (bi, hi // g, ik)),
+            pl.BlockSpec((1, 1, 1, block_k),
+                         lambda bi, hi, iq, ik, g=g: (bi, hi // g, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d_p),
                                lambda bi, hi, iq, ik: (bi, hi, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d_p), q.dtype),
         scratch_shapes=[
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q, d_p), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d_p), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
-        **kwargs,
     )(q, k, v, k_valid)
     return out[:, :, :tq, :d]

@@ -32,6 +32,9 @@ Dispatch contract
   2. the ``REPRO_BACKEND`` environment variable (global override),
   3. ``QuokaConfig.backend`` when not "auto",
   4. hardware auto-detection: "pallas" on TPU, else "xla".
+The model resolves once and stores the result in its ``QuokaConfig``;
+QUOKA scoring passes that as the explicit argument, so the environment
+cannot switch a resolved call.
 
 All backends produce outputs equal within tolerance (enforced by
 tests/test_backend_parity.py); layout conversion BTHD <-> BHTD happens here.
@@ -44,12 +47,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_bhtd
 from repro.kernels.quoka_score import quoka_score_bhtd
 from repro.kernels.selected_attention import (selected_attention_bhtd,
                                               selected_attention_paged)
+from repro.sharding import ctx as shctx
+from repro.sharding.specs import _maybe
 
 BACKENDS = ("xla", "pallas_interpret", "pallas")
 _ENV_VAR = "REPRO_BACKEND"
@@ -78,6 +84,36 @@ def resolve_backend(backend: Optional[str] = None, cfg=None) -> str:
     return be
 
 
+def _per_device(kernel, args, heads_dims, out_ndim: int):
+    """Call a Pallas ``kernel`` (BHTD layouts, batch on dim 0, heads on dim
+    ``heads_dims[i]`` of each arg and dim 1 of the output).
+
+    GSPMD cannot partition a Mosaic kernel, so under an active mesh policy
+    (sharding/ctx.py) the call runs inside a ``shard_map``: each device
+    takes its own batch rows (the FSDP axes) and heads (the ``model`` axis
+    where every heads dim divides it, else all heads).  Off-mesh, and in
+    the body of an enclosing ``shard_map`` (already per-device), it is a
+    plain call."""
+    mesh, b_axes, m_ax = shctx.get_policy()
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(*args)
+    b_ax = _maybe(mesh, args[0].shape[0], b_axes or None)
+    h_ax = m_ax if all(_maybe(mesh, x.shape[hd], m_ax)
+                       for x, hd in zip(args, heads_dims)
+                       if hd is not None) else None
+
+    def spec(ndim, hd):
+        s = [b_ax] + [None] * (ndim - 1)
+        if hd is not None:
+            s[hd] = h_ax
+        return P(*s)
+
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=tuple(spec(x.ndim, hd) for x, hd in zip(args, heads_dims)),
+        out_specs=spec(out_ndim, 1), check_vma=False)(*args)
+
+
 def attention(q, k, v, k_valid=None, *, causal: bool = True,
               boundary: int = 0, scale: Optional[float] = None,
               backend: Optional[str] = None, cfg=None):
@@ -100,9 +136,13 @@ def attention(q, k, v, k_valid=None, *, causal: bool = True,
                                           boundary=boundary, k_valid=k_valid,
                                           scale=scale)
         else:
-            out = flash_attention_bhtd(qt, kt, vt, k_valid, causal=causal,
-                                       boundary=boundary, scale=scale,
-                                       interpret=(be != "pallas"))
+            if k_valid is None:
+                k_valid = jnp.ones((q.shape[0], k.shape[1]), bool)
+            kernel = functools.partial(
+                flash_attention_bhtd, causal=causal, boundary=boundary,
+                scale=scale, interpret=(be != "pallas"))
+            out = _per_device(kernel, (qt, kt, vt, k_valid),
+                              (1, 1, 1, 1 if k_valid.ndim == 3 else None), 4)
         return out.transpose(0, 2, 1, 3)
 
 
@@ -302,7 +342,9 @@ def score(qbar, k, valid, *, backend: Optional[str] = None, cfg=None,
             return _score_xla(qbar, k, valid)
         qt = qbar.transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
-        return quoka_score_bhtd(qt, kt, valid, interpret=(be != "pallas"))
+        kernel = functools.partial(quoka_score_bhtd,
+                                   interpret=(be != "pallas"))
+        return _per_device(kernel, (qt, kt, valid), (1, 1, None), 3)
 
 
 # ---------------------------------------------------------------------------

@@ -8,24 +8,21 @@ once and writes one fp32 score per key, ~the streaming lower bound.
 
 Grid: (b, n_kv, T/block_t); block working set = block_t × d key tile.
 Validated on CPU with interpret=True against kernels/ref.py::quoka_score_ref.
+
+Mosaic tiling: every block's last two dims are multiples of (8, 128) or
+equal the array's own, so validity (int32, not bool) and the scores carry
+a unit second-minor axis — ``(b, 1, t)`` in ``(1, 1, block_t)`` blocks and
+``(b, n_kv, 1, t)`` in ``(1, 1, 1, block_t)`` blocks — and the max over
+queries keeps its axis, so no 1-D vector reaches the kernel.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -37,8 +34,8 @@ def _kernel(qbar_ref, k_ref, valid_ref, o_ref):
     kn = kb * inv                                       # normalise in-tile
     s = jax.lax.dot_general(qb, kn, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (n_q, bt)
-    smax = s.max(axis=0)                                # max over queries
-    o_ref[0, 0] = jnp.where(valid_ref[0], smax, NEG_INF)
+    smax = s.max(axis=0, keepdims=True)                 # (1, bt) over queries
+    o_ref[0, 0] = jnp.where(valid_ref[0] != 0, smax, NEG_INF)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -55,8 +52,9 @@ def quoka_score_bhtd(qbar, k, valid, *, block_t: int = 512,
     pq = (-n_q) % 8 if not interpret else 0
     if pt or pd:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pt), (0, pd)))
+    valid = valid.astype(jnp.int32)[:, None, :]         # (b, 1, t)
     if pt:
-        valid = jnp.pad(valid, ((0, 0), (0, pt)))
+        valid = jnp.pad(valid, ((0, 0), (0, 0), (0, pt)))
     if pd:
         qbar = jnp.pad(qbar, ((0, 0), (0, 0), (0, 0), (0, pd)))  # zeros: dot-safe
     if pq:
@@ -66,10 +64,6 @@ def quoka_score_bhtd(qbar, k, valid, *, block_t: int = 512,
     t_p, d_p, q_p = t + pt, d + pd, n_q + pq
     grid = (b, n_kv, t_p // block_t)
 
-    kwargs = {}
-    if not interpret and _COMPILER_PARAMS is not None:  # pragma: no cover
-        kwargs["compiler_params"] = _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "parallel"))
     out = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -77,12 +71,13 @@ def quoka_score_bhtd(qbar, k, valid, *, block_t: int = 512,
             pl.BlockSpec((1, 1, q_p, d_p), lambda bi, hi, it: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, block_t, d_p),
                          lambda bi, hi, it: (bi, hi, it, 0)),
-            pl.BlockSpec((1, block_t), lambda bi, hi, it: (bi, it)),
+            pl.BlockSpec((1, 1, block_t), lambda bi, hi, it: (bi, 0, it)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_t),
-                               lambda bi, hi, it: (bi, hi, it)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, t_p), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1, block_t),
+                               lambda bi, hi, it: (bi, hi, 0, it)),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, 1, t_p), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-        **kwargs,
     )(qbar, k, valid)
-    return out[:, :, :t]
+    return out[:, :, 0, :t]

@@ -39,18 +39,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
-
-try:  # TPU compiler params / grid specs are optional on CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape, dtype: pltpu.VMEM(shape, dtype)
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = None
-    _COMPILER_PARAMS = None
 
 NEG_INF = -1e30
 
@@ -158,13 +149,6 @@ def _resolve_tiles(t, T, d, n_kv, g, nb, block_q, block_k,
     return block_q, bk, semantics
 
 
-def _compiler_kwargs(interpret, semantics):
-    if not interpret and _COMPILER_PARAMS is not None:  # pragma: no cover
-        return {"compiler_params":
-                _COMPILER_PARAMS(dimension_semantics=semantics)}
-    return {}
-
-
 def _norm_inputs(q, idx, chunk_start, n_kv):
     b = q.shape[0]
     idx = idx.astype(jnp.int32)
@@ -255,16 +239,16 @@ def selected_attention_bhtd(q, k, v, key_pos, block_idx, chunk_start, *,
                                lambda bi, hi, iq, ik, idx_ref, start_ref:
                                (bi, hi, iq, 0)),
         scratch_shapes=[
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q, d_p), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, d_p), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t + pq, d_p), q.dtype),
         interpret=interpret,
-        **_compiler_kwargs(interpret, semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
     )(idx, start, q, k, v, key_pos.astype(jnp.int32))
     return out[:, :, :t, :d]
 
@@ -389,16 +373,16 @@ def selected_attention_paged(q, k_pool, v_pool, pos_pool, block_idx,
                                lambda bi, hi, iq, ik, *refs:
                                (bi, hi, iq, 0)),
         scratch_shapes=[
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q,), jnp.float32),
-            _SCRATCH((block_q, d_p), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, d_p), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t + pq, d_p), q.dtype),
         interpret=interpret,
-        **_compiler_kwargs(interpret, semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
     )(idx, start, table.astype(jnp.int32), q, k_pool, v_pool,
       pos_pool.astype(jnp.int32))
     return out[:, :, :t, :d]

@@ -1,8 +1,21 @@
 """Production mesh builders (functions, not module constants — importing
-this module never touches jax device state)."""
+this module never touches jax device state).
+
+Every mesh is built with ``Auto`` axis types: the sharding rules
+(sharding/specs.py) and activation constraints (sharding/ctx.py) are
+GSPMD-style hints that the compiler propagates.  ``jax.make_mesh`` defaults
+to ``Explicit`` axes, under which an op whose output sharding cannot be
+inferred (the embedding gather of ``P(model, data)`` rows by
+data-sharded tokens) is a trace-time error."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,13 +24,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     slow DCI axis and carries only data-parallel gradient reduction."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int = 1):
     """Small mesh for CPU sharding tests (run under
     XLA_FLAGS=--xla_force_host_platform_device_count=N)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str):
@@ -50,4 +63,4 @@ def mesh_from_spec(spec: str):
             f"mesh {spec!r} needs {n} devices but {have} are visible "
             f"(CPU runs: XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={n})")
-    return jax.make_mesh(tuple(axes.values()), tuple(axes))
+    return _mesh(axes.values(), axes)

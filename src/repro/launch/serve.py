@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.selection import METHODS
+from repro.launch import compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import Engine
 from repro.serving.request import make_requests
@@ -305,6 +306,7 @@ def main():
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N before launch)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -318,7 +320,7 @@ def main():
     cfg = dataclasses.replace(cfg, quoka=dataclasses.replace(
         q, chunk_size=min(q.chunk_size, args.prompt_len)))
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(0), dtype=cfg.compute_dtype)
     if args.ckpt:
         params = ckpt.restore(args.ckpt, params)
 

@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.data.synthetic import lm_batches, needle_batches
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import build_model
 from repro.sharding import ctx as shctx
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--save", default=None, help="checkpoint path")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -3,8 +3,9 @@
 Conventions:
   * every layer is a pair of pure functions ``<name>_init(key, ...) -> params``
     and ``<name>(params, x, ...) -> y``;
-  * params are nested dicts of jnp arrays; leaves are created in fp32 and
-    cast to the compute dtype at apply time by the caller's policy.
+  * params are nested dicts of jnp arrays; leaves are created in fp32
+    (``Model.init(key, dtype)`` casts them for serving) and cast to the
+    compute dtype at apply time by the caller's policy.
 """
 from __future__ import annotations
 

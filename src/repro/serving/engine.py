@@ -39,6 +39,7 @@ class GenerationResult:
                                   # excluded — per-token TTFT normalisation)
     method: str
     backend: str = "auto"         # resolved kernel backend of this run
+    logits: Optional[jax.Array] = None   # (b, V) fp32 first-token logits
 
 
 @dataclass
@@ -134,6 +135,15 @@ class Engine:
         # Benchmarks stamp this onto their records as the `fused` axis.
         self.fused = bool(getattr(model.cfg.quoka, "fused_select_attn",
                                   False))
+        if self.fused and self.backend == "pallas":
+            raise ValueError(
+                "fused_select_attn=True does not compile on the 'pallas' "
+                "backend: kernels/selected_attention.py streams K in tiles "
+                "of at most `granularity` rows with (1, tile) key-position "
+                "blocks, and Mosaic requires a block's last two dims to be "
+                "multiples of (8, 128) or equal to the array's own.  Serve "
+                "with fused_select_attn=False (the staged path), or use "
+                "backend 'xla' / 'pallas_interpret' for parity runs")
         self.sampler = sampler
         self.registry = registry if registry is not None else obs_reg.NULL
         self._obs_on = bool(self.registry.enabled)
@@ -255,6 +265,7 @@ class Engine:
         tok = sample(logits, key, self.sampler)
         tok.block_until_ready()
         ttft = time.perf_counter() - t0
+        first_logits = logits
 
         # device-side accumulation: one host transfer AFTER the loop.  A
         # per-step np.asarray(tok) forces a device->host sync per token and
@@ -282,7 +293,8 @@ class Engine:
                           if pad is not None else 0)
         return GenerationResult(tokens=tokens_out, ttft_s=ttft,
                                 decode_tps=tps, prompt_len=prompt_len,
-                                method=self.method, backend=self.backend)
+                                method=self.method, backend=self.backend,
+                                logits=first_logits)
 
     # ------------------------------------------------------------------
     # continuous batching

@@ -42,9 +42,8 @@ Prefix caching (multi-turn chats, shared system prompts):
 Hierarchical pool (``host_tier_blocks > 0``): a host-memory tier sits
 behind the device pool, Double Sparsity-style.  Pressure-eviction DEMOTES
 a registered block — content + registration move to a pinned host buffer
-(``jax.device_put`` onto the ``pinned_host`` memory kind where the backend
-has one; on CPU device memory already is host memory) instead of being
-pos=-1-stamped away, so eviction becomes tiering rather than cache loss.
+(``jax.device_put`` onto the device's ``pinned_host`` memory kind) instead
+of being pos=-1-stamped away, so eviction becomes tiering rather than cache loss.
 ``match_prefix`` walks the hash chain across BOTH tiers; a host match is
 PROMOTED at allocation time — an async H2D ``jax.device_put`` plus a
 jitted block write into a fresh device block, re-registered on device so
@@ -69,6 +68,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 _UNSUPPORTED_KINDS = ("mamba", "mamba_shared_attn", "rwkv", "dec_cross",
                       "attn_local")
@@ -148,21 +148,6 @@ def _chain_hashes(tokens: np.ndarray, block_size: int) -> List[int]:
     return out
 
 
-def _host_placement():
-    """Placement fn for demoted block slabs: pinned host memory where the
-    backend exposes the ``pinned_host`` memory kind (H2D from pinned pages
-    is what lets ``jax.device_put`` overlap compute on GPU/TPU); on CPU the
-    device memory already IS host memory, so slabs stay where they are; any
-    other backend without the memory kind falls back to numpy."""
-    try:
-        mem = jax.devices()[0].memory("pinned_host")
-        return lambda slab: jax.device_put(slab, mem)
-    except Exception:
-        if jax.default_backend() == "cpu":
-            return lambda slab: slab
-        return lambda slab: jax.tree.map(np.asarray, slab)
-
-
 class HostTier:
     """Slot-addressed host-memory store of demoted block slabs + LRU.
 
@@ -170,14 +155,19 @@ class HostTier:
     stay on ``PagedKVCache`` (mirroring the device tier's ``_reg``/
     ``_full``/``_tail``) so ``check_invariants`` covers both tiers in one
     place.  The pool drives eviction: ``oldest()`` names the victim, the
-    pool unregisters it, then ``drop`` releases the slot."""
+    pool unregisters it, then ``drop`` releases the slot.
 
-    def __init__(self, capacity: int):
+    Slabs live in ``device``'s ``pinned_host`` memory (H2D from pinned
+    pages is what lets ``jax.device_put`` overlap compute); ``fetch``
+    copies one back into the device's own memory."""
+
+    def __init__(self, capacity: int, device):
         self.capacity = int(capacity)
         self._free: List[int] = list(range(self.capacity))
         self._slabs: Dict[int, object] = {}
         self._lru: "OrderedDict[int, None]" = OrderedDict()
-        self._place = _host_placement()
+        self._host = SingleDeviceSharding(device, memory_kind="pinned_host")
+        self._device = SingleDeviceSharding(device, memory_kind="device")
 
     def __len__(self) -> int:
         return len(self._slabs)
@@ -192,12 +182,13 @@ class HostTier:
     def put(self, slab) -> int:
         """Store one block slab (caller ensured a free slot)."""
         slot = self._free.pop()
-        self._slabs[slot] = self._place(slab)
+        self._slabs[slot] = jax.device_put(slab, self._host)
         self._lru[slot] = None                     # MRU end
         return slot
 
-    def get(self, slot: int):
-        return self._slabs[slot]
+    def fetch(self, slot: int):
+        """Async H2D copy of slot ``slot``'s slab into device memory."""
+        return jax.device_put(self._slabs[slot], self._device)
 
     def touch(self, slot: int) -> None:
         self._lru.move_to_end(slot)
@@ -250,7 +241,8 @@ class PagedKVCache:
         self._tail: Dict[int, int] = {}             # prefix hash -> block
         # ---- host tier (hierarchical pool; see module docstring;
         # mesh-incompatibility guarded at the top of __init__) ----
-        self.host: Optional[HostTier] = (HostTier(host_tier_blocks)
+        dev = next(iter(jax.tree.leaves(self.data)[0].devices()))
+        self.host: Optional[HostTier] = (HostTier(host_tier_blocks, dev)
                                          if host_tier_blocks else None)
         self._h_reg: Dict[int, Tuple] = {}          # host slot -> registration
         self._h_full: Dict[int, int] = {}           # chain hash -> host slot
@@ -654,7 +646,7 @@ class PagedKVCache:
         if slab is not None:
             self.staged_used += 1
         else:
-            slab = jax.device_put(self.host.get(slot))
+            slab = self.host.fetch(slot)
         self.host.drop(slot)
         return slab, reg
 
@@ -667,7 +659,7 @@ class PagedKVCache:
         if slab is not None:
             self.staged_used += 1
             return slab
-        return jax.device_put(self.host.get(slot))
+        return self.host.fetch(slot)
 
     def stage(self, slot: int) -> bool:
         """Dispatch the H2D copy for host slot ``slot`` ahead of its
@@ -676,7 +668,7 @@ class PagedKVCache:
         returns True when a new copy was started."""
         if self.host is None or slot in self._staged:
             return False
-        self._staged[slot] = jax.device_put(self.host.get(slot))
+        self._staged[slot] = self.host.fetch(slot)
         return True
 
     def _stamp(self, blocks: List[int]) -> None:

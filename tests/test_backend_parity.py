@@ -193,6 +193,26 @@ def test_model_prefill_backend_parity_and_kernel_use(monkeypatch):
                                atol=5e-4, rtol=5e-3)
 
 
+@pytest.mark.parametrize("env,backend,kernels", [
+    ("pallas_interpret", "xla", False), ("xla", "pallas_interpret", True)])
+def test_model_backend_outranks_env(monkeypatch, env, backend, kernels):
+    """An exported REPRO_BACKEND cannot switch a model call's backend, QUOKA
+    scoring included: the kernels run exactly when the call asks for them."""
+    calls = []
+    for name in ("flash_attention_bhtd", "quoka_score_bhtd"):
+        real = getattr(kops, name)
+        monkeypatch.setattr(kops, name, lambda *a, _r=real, _n=name, **k: (
+            calls.append(_n), _r(*a, **k))[1])
+    monkeypatch.setenv("REPRO_BACKEND", env)
+    model, params, cfg = _smoke_model()
+    toks = jnp.asarray(
+        np.random.default_rng(0).integers(3, cfg.vocab, (2, 64)), jnp.int32)
+    model.prefill(params, {"tokens": toks}, model.init_cache(2, 80), "quoka",
+                  backend=backend)
+    assert set(calls) == ({"flash_attention_bhtd", "quoka_score_bhtd"}
+                          if kernels else set())
+
+
 def test_engine_generate_backend_parity():
     from repro.serving.engine import Engine
     from repro.serving.sampler import SamplerConfig
@@ -209,6 +229,24 @@ def test_engine_generate_backend_parity():
         outs[be] = r.tokens
     # greedy sampling: identical numerics within tolerance -> same tokens
     assert (outs["xla"] == outs["pallas_interpret"]).all()
+
+
+def test_engine_generate_returns_first_token_logits():
+    """``generate`` returns the prefill's logits, equal to a direct
+    ``Model.prefill``, and the greedy first token is their argmax."""
+    from repro.serving.engine import Engine
+    model, params, cfg = _smoke_model()
+    toks = jnp.asarray(
+        np.random.default_rng(3).integers(3, cfg.vocab, (2, 48)), jnp.int32)
+    eng = Engine(model, params, method="quoka", backend="xla")
+    r = eng.generate({"tokens": toks}, 2)
+    want, _ = model.prefill(params, {"tokens": toks}, model.init_cache(2, 50),
+                            "quoka", backend="xla")
+    assert r.logits.shape == (2, cfg.vocab)
+    np.testing.assert_allclose(np.asarray(r.logits), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(r.tokens[:, 0],
+                                  np.argmax(np.asarray(r.logits), -1))
 
 
 def test_mla_prefill_backend_parity():

@@ -329,13 +329,16 @@ def test_compiled_kernels_match_oracle():
     if jax.default_backend() == "cpu":
         pytest.skip("compiled Pallas kernels need a TPU/GPU backend; "
                     "CPU CI covers the interpret-mode parity suite")
-    q, k, v, key_pos, idx, start, g = _fused_case(FUSED_CASES[0])
-    want = _staged_selected(q, k, v, key_pos, idx, start, g)
-    out = kops.selected_attention(q, k, v, key_pos, idx, jnp.int32(start),
-                                  granularity=g, backend="pallas")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=2e-2, rtol=2e-2)
+    # the fused selected-attention kernel does not compile for Mosaic
+    # (tests/test_tpu_compile.py pins that); flash and scoring do
+    q, k, v, key_pos, *_ = _fused_case(FUSED_CASES[0])
     o_flash = flash_attention(q, k, v, backend="pallas")
     w_flash = flash_attention(q, k, v, backend="xla")
     np.testing.assert_allclose(np.asarray(o_flash), np.asarray(w_flash),
                                atol=2e-2, rtol=2e-2)
+    valid = key_pos >= 0
+    qbar = k[:, :8] / jnp.linalg.norm(k[:, :8], axis=-1, keepdims=True)
+    np.testing.assert_allclose(
+        np.asarray(kops.score(qbar, k, valid, backend="pallas")),
+        np.asarray(kops.score(qbar, k, valid, backend="xla")),
+        atol=2e-2, rtol=2e-2)

@@ -190,3 +190,21 @@ def test_mla_absorbed_equals_explicit():
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(want.reshape(b, t, -1)),
                                atol=2e-4, rtol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# serving params: created in the compute dtype
+# ---------------------------------------------------------------------------
+
+def test_init_in_compute_dtype_equals_cast_fp32_init():
+    from repro.models.model import build_model
+    model = build_model(get_config("qwen3-4b").smoke())
+    f32 = model.init(KEY)
+    bf16 = model.init(KEY, dtype=jnp.bfloat16)
+    assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(f32))
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(bf16))
+    # one jitted program may round the fp32 init differently in the last
+    # bit before the cast: allow one bf16 ulp
+    for a, b in zip(jax.tree.leaves(f32), jax.tree.leaves(bf16)):
+        np.testing.assert_allclose(np.asarray(b, np.float32), np.asarray(a),
+                                   rtol=2 ** -7, atol=0)

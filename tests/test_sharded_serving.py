@@ -94,6 +94,34 @@ SUBPROC = textwrap.dedent("""
     k_bytes = b * t * n_kv * d * 4
     out["score_allgather_bytes"] = coll.get("all-gather", 0)
     out["k_cache_bytes"] = k_bytes
+
+    # ---- Pallas kernels under the mesh run per device (shard_map) ----
+    from repro.kernels import ops as kops
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    q8 = jax.random.normal(ks[0], (2, 16, 8, 64), jnp.float32)
+    k4 = jax.random.normal(ks[1], (2, 48, 4, 64), jnp.float32)
+    v4 = jax.random.normal(ks[2], (2, 48, 4, 64), jnp.float32)
+    kv_ok = jax.random.bernoulli(ks[3], 0.8, (2, 4, 48))
+    qbar = jax.random.normal(ks[4], (2, 4, 4, 64), jnp.float32)
+
+    def kernels(q, k, v, ok, qb):
+        return (kops.attention(q, k, v, ok, boundary=32,
+                               backend="pallas_interpret"),
+                kops.score(qb, k, ok[:, 0], backend="pallas_interpret"))
+
+    args = (q8, k4, v4, kv_ok, qbar)
+    want = jax.jit(kernels)(*args)
+    shctx.set_policy(mesh, ("data",))
+    try:
+        with mesh:
+            got = jax.jit(kernels)(*args)
+            jaxpr = str(jax.make_jaxpr(kernels)(*args))
+    finally:
+        shctx.restore_policy(snap)
+    out["pallas_per_device"] = bool(
+        jaxpr.count("shard_map") >= 2 and all(
+            np.allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+            for g, w in zip(got, want)))
     print("RESULT", json.dumps(out))
 """)
 
@@ -129,3 +157,10 @@ def test_sharded_scoring_no_kv_cache_allgather(subproc_result):
     kb = subproc_result["k_cache_bytes"]
     assert ag > 0, "shard_map path did not engage (no candidate merge)"
     assert ag < kb / 4, (ag, kb)
+
+
+def test_sharded_pallas_kernels_run_per_device(subproc_result):
+    """GSPMD cannot partition a Mosaic kernel: under the mesh policy the
+    flash and scoring kernels run inside a shard_map (batch over data,
+    heads over model) and equal the meshless call."""
+    assert subproc_result["pallas_per_device"], subproc_result
