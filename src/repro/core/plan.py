@@ -66,14 +66,12 @@ class PlanCarry(NamedTuple):
 
 
 class LayerObs(NamedTuple):
-    """Per-layer in-jit selection telemetry — seven device SCALARS (f32),
+    """Per-layer in-jit selection telemetry — four device SCALARS (f32),
     cheap enough to ride out of ``jax.jit`` as extra outputs (the aux-stats
     pytree contract; no host callbacks on the hot path).
 
     NaN means "not applicable": non-attention layers are all-NaN; dense
-    layers have no budget/sketch; a layer that REUSED a carried plan has a
-    NaN sketch (scores were never computed — the sketch is a ``lax.cond``
-    output and only the build branch produces one).
+    layers have no budget.
 
     sel_tokens    selected valid KV tokens, mean over batch & KV heads
     ctx_tokens    selectable prior-context tokens, mean over batch
@@ -81,17 +79,11 @@ class LayerObs(NamedTuple):
                   — the paper's "88% fewer key-value pairs" axis)
     budget_tokens the resolved grid-aligned B_SA (static, as f32)
     refreshed     1.0 if this layer BUILT a plan, 0.0 if it reused one
-    score_lo/score_mean/score_hi
-                  sketch of the raw stage-1 score distribution over valid
-                  slots, taken BEFORE sink +inf stamping
     """
     sel_tokens: jax.Array
     ctx_tokens: jax.Array
     budget_tokens: jax.Array
     refreshed: jax.Array
-    score_lo: jax.Array
-    score_mean: jax.Array
-    score_hi: jax.Array
 
 
 # ----------------------------------------------------------------------------
@@ -159,6 +151,11 @@ def plan_from_scores(scores: jax.Array, key_pos: jax.Array,
     Sink protection first force-keeps the ``keep_first`` earliest real
     tokens (their blocks, at g > 1) by stamping +inf onto valid slots.
     """
+    with jax.named_scope("plan_topk"):
+        return _plan_from_scores(scores, key_pos, cfg, budget)
+
+
+def _plan_from_scores(scores, key_pos, cfg, budget):
     b, n_kv, t = scores.shape
     g = grid(cfg)
     budget = floor_to_grid(min(budget or sel_scores.resolve_budget(cfg, t),
@@ -274,12 +271,14 @@ def build(method: str, q, k, key_pos, chunk_start, cfg: QuokaConfig,
     if method == "quoka":
         info = qk._tp_route(k, cfg)
         if info is not None:
-            q = qk.sanitize_queries(q, q_valid)
-            qs = qk.subselect_queries(q, cfg.n_queries, n_kv=k.shape[2],
-                                      q_valid=q_valid)
-            valid = prior_context_valid(key_pos, chunk_start)
-            return SelectionPlan(idx=qk.tp_plan_candidates(
-                qs, k, key_pos, valid, cfg, budget, info))
+            # scoring and the candidate top-k share one shard_map
+            with jax.named_scope("plan_scores"):
+                q = qk.sanitize_queries(q, q_valid)
+                qs = qk.subselect_queries(q, cfg.n_queries, n_kv=k.shape[2],
+                                          q_valid=q_valid)
+                valid = prior_context_valid(key_pos, chunk_start)
+                return SelectionPlan(idx=qk.tp_plan_candidates(
+                    qs, k, key_pos, valid, cfg, budget, info))
     scores = plan_scores(method, q, k, key_pos, chunk_start, cfg,
                          q_valid=q_valid)
     return plan_from_scores(scores, key_pos, cfg, budget=budget)
@@ -347,27 +346,7 @@ def nan_obs() -> LayerObs:
     encoder blocks) — keeps the per-layer stats pytree uniform so the stack
     scan can stack it as ys."""
     n = jnp.full((), jnp.nan, jnp.float32)
-    return LayerObs(n, n, n, n, n, n, n)
-
-
-def score_sketch(scores: jax.Array) -> jax.Array:
-    """(3,) f32 [min, mean, max] of stage-1 scores over VALID slots.
-
-    Must be taken on the raw ``plan_scores`` output: ``plan_from_scores``
-    stamps +inf onto sink slots, which would corrupt the max.  All-invalid
-    views (first chunk: no prior context) sketch as NaN.
-    """
-    ok = scores > NEG_INF / 2
-    n = jnp.sum(ok)
-    lo = jnp.min(jnp.where(ok, scores, jnp.inf))
-    hi = jnp.max(jnp.where(ok, scores, -jnp.inf))
-    mean = jnp.sum(jnp.where(ok, scores, 0.0)) / jnp.maximum(n, 1)
-    sk = jnp.stack([lo, mean, hi]).astype(jnp.float32)
-    return jnp.where(n > 0, sk, jnp.full((3,), jnp.nan, jnp.float32))
-
-
-def _nan_sketch() -> jax.Array:
-    return jnp.full((3,), jnp.nan, jnp.float32)
+    return LayerObs(n, n, n, n)
 
 
 def dense_obs(key_pos, chunk_start) -> LayerObs:
@@ -377,12 +356,11 @@ def dense_obs(key_pos, chunk_start) -> LayerObs:
     ctxc = jnp.mean(jnp.sum(valid, axis=-1).astype(jnp.float32))
     n = jnp.full((), jnp.nan, jnp.float32)
     return LayerObs(sel_tokens=ctxc, ctx_tokens=ctxc, budget_tokens=n,
-                    refreshed=jnp.zeros((), jnp.float32),
-                    score_lo=n, score_mean=n, score_hi=n)
+                    refreshed=jnp.zeros((), jnp.float32))
 
 
-def selected_obs(sel_pos, key_pos, chunk_start, budget: int, refreshed,
-                 sketch) -> LayerObs:
+def selected_obs(sel_pos, key_pos, chunk_start, budget: int,
+                 refreshed) -> LayerObs:
     """LayerObs for a selecting layer, from the materialized budget's
     validity (``sel_pos == -1`` marks padding — exactly what downstream
     attention masks, so sel_tokens counts KV pairs actually attended)."""
@@ -391,49 +369,16 @@ def selected_obs(sel_pos, key_pos, chunk_start, budget: int, refreshed,
     ctxc = jnp.mean(jnp.sum(valid, axis=-1).astype(jnp.float32))
     return LayerObs(sel_tokens=selc, ctx_tokens=ctxc,
                     budget_tokens=jnp.full((), float(budget), jnp.float32),
-                    refreshed=jnp.asarray(refreshed, jnp.float32),
-                    score_lo=sketch[0], score_mean=sketch[1],
-                    score_hi=sketch[2])
+                    refreshed=jnp.asarray(refreshed, jnp.float32))
 
 
-def build_obs(method: str, q, k, key_pos, chunk_start, cfg: QuokaConfig,
-              budget: Optional[int] = None,
-              q_valid: Optional[jax.Array] = None):
-    """``build`` that also returns the (3,) score sketch.  The TP T-local
-    route never materializes global scores, so it sketches NaN — plan
-    indices stay bit-exact with ``build`` in every branch."""
-    t = k.shape[1]
-    budget = floor_to_grid(min(budget or sel_scores.resolve_budget(cfg, t),
-                               t), grid(cfg))
-    if method == "quoka" and qk._tp_route(k, cfg) is not None:
-        return build(method, q, k, key_pos, chunk_start, cfg, budget=budget,
-                     q_valid=q_valid), _nan_sketch()
-    scores = plan_scores(method, q, k, key_pos, chunk_start, cfg,
-                         q_valid=q_valid)
-    return (plan_from_scores(scores, key_pos, cfg, budget=budget),
-            score_sketch(scores))
-
-
-def refresh_obs(carry: Optional[PlanCarry], layer_idx, cfg: QuokaConfig,
-                build_fn) -> tuple:
-    """``refresh`` for an obs-carrying ``build_fn`` (returns (plan, sketch)).
-
-    Returns ((plan, sketch), updated carry, refreshed () f32).  The sketch
-    is a ``lax.cond`` output: the reuse branch yields NaN (scores are never
-    computed there — that is the whole point of reuse)."""
+def refreshed_flag(carry: Optional[PlanCarry], layer_idx,
+                   cfg: QuokaConfig) -> jax.Array:
+    """() f32: 1.0 if ``refresh`` builds at this layer, 0.0 if it reuses
+    the carried plan (the ``LayerObs.refreshed`` counter)."""
     if carry is None:
-        pln, sk = build_fn()
-        return (pln, sk), None, jnp.ones((), jnp.float32)
-    do = _refresh_decision(carry, layer_idx, cfg)
-
-    def _built():
-        pln, sk = build_fn()
-        return pln.idx, sk
-
-    idx, sk = jax.lax.cond(do, _built, lambda: (carry.idx, _nan_sketch()))
-    return ((SelectionPlan(idx=idx), sk),
-            PlanCarry(idx=idx, valid=jnp.ones((), bool)),
-            do.astype(jnp.float32))
+        return jnp.ones((), jnp.float32)
+    return _refresh_decision(carry, layer_idx, cfg).astype(jnp.float32)
 
 
 def select_with_ctx(ctx, plan, method: str, q, k, v, key_pos, chunk_start,
@@ -448,23 +393,19 @@ def select_with_ctx(ctx, plan, method: str, q, k, v, key_pos, chunk_start,
     off this is byte-identical to the refresh + materialize it replaced.
     """
     li = ctx.get("layer_idx", 0)
-    if not ctx.get("obs"):
-        pln, plan = refresh(
-            plan, li, cfg,
-            lambda: build(method, q, k, key_pos, chunk_start, cfg,
-                          budget=budget, q_valid=q_valid))
-        _note_block_counts(ctx, pln, cfg)
-        return materialize(pln, k, v, key_pos, chunk_start, cfg), plan
-    t = k.shape[1]
-    bud = floor_to_grid(min(budget or sel_scores.resolve_budget(cfg, t), t),
-                        grid(cfg))
-    (pln, sketch), plan, refreshed = refresh_obs(
+    obs = ctx.get("obs")
+    refreshed = refreshed_flag(plan, li, cfg) if obs else None
+    pln, plan = refresh(
         plan, li, cfg,
-        lambda: build_obs(method, q, k, key_pos, chunk_start, cfg,
-                          budget=bud, q_valid=q_valid))
+        lambda: build(method, q, k, key_pos, chunk_start, cfg,
+                      budget=budget, q_valid=q_valid))
     sel = materialize(pln, k, v, key_pos, chunk_start, cfg)
-    ctx["_obs"] = selected_obs(sel.pos, key_pos, chunk_start, bud,
-                               refreshed, sketch)
+    if obs:
+        t = k.shape[1]
+        bud = floor_to_grid(
+            min(budget or sel_scores.resolve_budget(cfg, t), t), grid(cfg))
+        ctx["_obs"] = selected_obs(sel.pos, key_pos, chunk_start, bud,
+                                   refreshed)
     _note_block_counts(ctx, pln, cfg)
     return sel, plan
 
@@ -588,24 +529,17 @@ def fused_attend_with_ctx(ctx, plan, method: str, q, k, v, key_pos,
     t = k.shape[1]
     bud = floor_to_grid(min(budget or sel_scores.resolve_budget(cfg, t), t),
                         g)
-    if not ctx.get("obs"):
-        pln, plan = refresh(
-            plan, li, cfg,
-            lambda: build(method, q, k, key_pos, chunk_start, cfg,
-                          budget=bud, q_valid=q_valid))
-        att = kops.selected_attention(q, k, v, key_pos, pln.idx,
-                                      chunk_start, granularity=g,
-                                      backend=be, cfg=cfg)
-        _note_block_counts(ctx, pln, cfg)
-        return att, plan
-    (pln, sketch), plan, refreshed = refresh_obs(
+    obs = ctx.get("obs")
+    refreshed = refreshed_flag(plan, li, cfg) if obs else None
+    pln, plan = refresh(
         plan, li, cfg,
-        lambda: build_obs(method, q, k, key_pos, chunk_start, cfg,
-                          budget=bud, q_valid=q_valid))
+        lambda: build(method, q, k, key_pos, chunk_start, cfg,
+                      budget=bud, q_valid=q_valid))
     att = kops.selected_attention(q, k, v, key_pos, pln.idx, chunk_start,
                                   granularity=g, backend=be, cfg=cfg)
-    ctx["_obs"] = selected_obs(
-        plan_selected_pos(pln, key_pos, chunk_start, cfg), key_pos,
-        chunk_start, bud, refreshed, sketch)
+    if obs:
+        ctx["_obs"] = selected_obs(
+            plan_selected_pos(pln, key_pos, chunk_start, cfg), key_pos,
+            chunk_start, bud, refreshed)
     _note_block_counts(ctx, pln, cfg)
     return att, plan

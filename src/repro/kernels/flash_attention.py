@@ -137,6 +137,7 @@ def flash_attention_bhtd(q, k, v, k_valid=None, *, causal: bool = True,
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attention_bhtd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d_p),

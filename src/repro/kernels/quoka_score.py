@@ -66,6 +66,7 @@ def quoka_score_bhtd(qbar, k, valid, *, block_t: int = 512,
 
     out = pl.pallas_call(
         _kernel,
+        name="quoka_score_bhtd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, q_p, d_p), lambda bi, hi, it: (bi, hi, 0, 0)),

@@ -245,7 +245,7 @@ def selected_attention_bhtd(q, k, v, key_pos, block_idx, chunk_start, *,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="selected_attention_bhtd",
         out_shape=jax.ShapeDtypeStruct((b, h, t + pq, d_p), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
@@ -379,7 +379,7 @@ def selected_attention_paged(q, k_pool, v_pool, pos_pool, block_idx,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="selected_attention_paged",
         out_shape=jax.ShapeDtypeStruct((b, h, t + pq, d_p), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),

@@ -115,12 +115,14 @@ class AttnBlock:
 
     def _ffn(self, p, x, ctx):
         cfg = self.cfg
-        h = self.norm(p["ln2"], x)
-        if self.is_moe:
-            y = moe.moe_apply(p["moe"], h, cfg, ctx)
-            aux = ctx.pop("aux_loss", 0.0) if isinstance(ctx, dict) else 0.0
-            return x + y, aux
-        return x + mlp(p["mlp"], h, cfg.act), 0.0
+        with jax.named_scope("mlp"):
+            h = self.norm(p["ln2"], x)
+            if self.is_moe:
+                y = moe.moe_apply(p["moe"], h, cfg, ctx)
+                aux = ctx.pop("aux_loss", 0.0) if isinstance(ctx, dict) \
+                    else 0.0
+                return x + y, aux
+            return x + mlp(p["mlp"], h, cfg.act), 0.0
 
     # ---- modes ----
     def train(self, p, x, pos, ctx):
@@ -163,7 +165,8 @@ class AttnBlock:
             y, aux = self.train(p, x, pos, ctx)
             return y, cache, aux, plan
         b, t, _ = x.shape
-        q, k, v = self._qkv(p, self.norm(p["ln1"], x), pos)
+        with jax.named_scope("attn_proj"):
+            q, k, v = self._qkv(p, self.norm(p["ln1"], x), pos)
         start = _chunk_slot(ctx, pos)
         kv = cache.kv
         write = kv_write_ring if self.window is not None else kv_write
@@ -192,7 +195,8 @@ class AttnBlock:
                 ctx["qcfg"], budget=budget, q_valid=pos >= 0)
             att = self._selected_attention(q, k, v, pos, sel,
                                            backend=ctx.get("backend"))
-        x = x + linear(p["wo"], att.reshape(b, t, -1))
+        with jax.named_scope("attn_proj"):
+            x = x + linear(p["wo"], att.reshape(b, t, -1))
         x, aux = self._ffn(p, x, dict(ctx) if ctx else {})
         return x, cache._replace(kv=kv), aux, plan
 

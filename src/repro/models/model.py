@@ -147,10 +147,11 @@ class Model:
         return ctx
 
     def _readout(self, p, x) -> jax.Array:
-        x = rmsnorm(p["ln_f"], x, self.cfg.norm_eps)
-        if self.cfg.tie_embeddings:
-            return unembed(p["embed"], x)
-        return linear(p["lm_head"], x.astype(jnp.float32))
+        with jax.named_scope("readout"):
+            x = rmsnorm(p["ln_f"], x, self.cfg.norm_eps)
+            if self.cfg.tie_embeddings:
+                return unembed(p["embed"], x)
+            return linear(p["lm_head"], x.astype(jnp.float32))
 
     # ------------------------------------------------------------------
     # training forward
@@ -323,16 +324,17 @@ class Model:
         tok = batch["tokens"]
         b, t = tok.shape
         dt = cfg.compute_dtype
-        x = embed(p["embed"], tok, dt)
-        s = jnp.asarray(pos_start, jnp.int32)
-        offs = jnp.arange(t, dtype=jnp.int32)
-        pos = (s + offs)[None].repeat(b, 0) if s.ndim == 0 \
-            else s[:, None] + offs[None]
-        if valid_len is not None:
-            vl = jnp.asarray(valid_len, jnp.int32)
-            pos = jnp.where(offs[None] < vl[:, None], pos, -1)
-        if not cfg.use_rope:
-            x = x + sinusoidal(pos, cfg.d_model, dt)
+        with jax.named_scope("embed"):
+            x = embed(p["embed"], tok, dt)
+            s = jnp.asarray(pos_start, jnp.int32)
+            offs = jnp.arange(t, dtype=jnp.int32)
+            pos = (s + offs)[None].repeat(b, 0) if s.ndim == 0 \
+                else s[:, None] + offs[None]
+            if valid_len is not None:
+                vl = jnp.asarray(valid_len, jnp.int32)
+                pos = jnp.where(offs[None] < vl[:, None], pos, -1)
+            if not cfg.use_rope:
+                x = x + sinusoidal(pos, cfg.d_model, dt)
         from repro.sharding import ctx as shctx
         x = shctx.shard_activation(x)
         ctx = self._ctx(p, method, backend=backend)
@@ -342,11 +344,13 @@ class Model:
         if sel_blocks is not None:
             ctx["selblk"] = (int(sel_blocks[0]), int(sel_blocks[1]))
         x, cache, _, obs, sel = self._apply_stacks(p, x, pos, cache, ctx)
-        if valid_len is None:
-            last = x[:, -1, :]
-        else:
-            li = jnp.clip(vl - 1, 0, t - 1)
-            last = jnp.take_along_axis(x, li[:, None, None], axis=1)[:, 0, :]
+        with jax.named_scope("readout"):
+            if valid_len is None:
+                last = x[:, -1, :]
+            else:
+                li = jnp.clip(vl - 1, 0, t - 1)
+                last = jnp.take_along_axis(x, li[:, None, None],
+                                           axis=1)[:, 0, :]
         out = (last, cache)
         if with_obs:
             out = out + (obs,)
@@ -368,11 +372,12 @@ class Model:
         method = method or cfg.quoka.method
         dt = cfg.compute_dtype
         b = tokens.shape[0]
-        x = embed(p["embed"], tokens[:, None], dt)
-        ps = jnp.asarray(pos, jnp.int32)
-        pos2 = jnp.broadcast_to(ps.reshape(-1, 1), (b, 1))
-        if not cfg.use_rope:
-            x = x + sinusoidal(pos2, cfg.d_model, dt)
+        with jax.named_scope("embed"):
+            x = embed(p["embed"], tokens[:, None], dt)
+            ps = jnp.asarray(pos, jnp.int32)
+            pos2 = jnp.broadcast_to(ps.reshape(-1, 1), (b, 1))
+            if not cfg.use_rope:
+                x = x + sinusoidal(pos2, cfg.d_model, dt)
         ctx = self._ctx(p, method, backend=backend)
         ctx["slot"] = ps
         if with_obs:

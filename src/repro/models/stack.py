@@ -212,7 +212,8 @@ class Stack:
                     sb = cj.pop("_selblk", None)
                     sel_j.append(jnp.zeros((h.shape[0], ctx["selblk"][1]),
                                            jnp.int32) if sb is None else sb)
-                new_bufs.append(write_back(blk, bufs[j], c_new, idx))
+                with jax.named_scope("kv_write"):
+                    new_bufs.append(write_back(blk, bufs[j], c_new, idx))
                 aux = aux + jnp.asarray(a, jnp.float32)
             out = (h, aux, tuple(new_bufs))
             ys = (jax.tree.map(lambda *ls: jnp.stack(ls), *obs_j)
@@ -224,7 +225,12 @@ class Stack:
         init = (x, jnp.zeros((), jnp.float32), caches)
         if carry0 is not None:
             init = init + (carry0,)
-        out, ys = jax.lax.scan(body, init, (params, idxs))
+        # names the scan's own work: each layer's weights and cache view
+        # sliced out of the stacked buffers.  An op in the body with no
+        # scope of its own inherits it too, so a reader of the op metadata
+        # counts it as this scope's only on those slices (bench/scopes.py)
+        with jax.named_scope("stack"):
+            out, ys = jax.lax.scan(body, init, (params, idxs))
         if carry0 is not None:
             x, aux, caches, plan = out
         else:

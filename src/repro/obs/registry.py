@@ -33,6 +33,7 @@ import time
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class Counter:
@@ -160,7 +161,6 @@ class _Span:
 
     def __enter__(self):
         try:
-            from jax.profiler import TraceAnnotation
             self._ann = TraceAnnotation(self._name)
             self._ann.__enter__()
         except Exception:          # profiler unavailable: spans still work
@@ -169,16 +169,10 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        reg = self._reg
-        reg.histogram(self._name).observe(dt)
-        ev = {"name": self._name, "ph": "X", "pid": 1, "tid": 1,
-              "ts": (self._t0 - reg.t0) * 1e6, "dur": dt * 1e6}
-        if self._args:
-            ev["args"] = self._args
-        reg.trace_events.append(ev)
+        self._reg._add_span(self._name, self._t0, t1, self._args)
         return False
 
 
@@ -244,6 +238,24 @@ class Registry:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args or None)
+
+    def record(self, name: str, start: float, end: float, **args) -> None:
+        """A span known only after the fact (a request's queue wait, its
+        prefill, its decode): ``start``/``end`` are ``time.perf_counter()``
+        readings.  It lands in the histogram and the trace events as a
+        ``span`` would, but not on the profiler's timeline; place it there
+        through a matched pair of spans that are on both clocks."""
+        if self.enabled:
+            self._add_span(name, start, end, args or None)
+
+    def _add_span(self, name: str, start: float, end: float,
+                  args: Optional[Dict]) -> None:
+        self.histogram(name).observe(end - start)
+        ev = {"name": name, "ph": "X", "pid": 1, "tid": 1,
+              "ts": (start - self.t0) * 1e6, "dur": (end - start) * 1e6}
+        if args:
+            ev["args"] = args
+        self.trace_events.append(ev)
 
     def event(self, kind: str, **fields) -> None:
         if not self.enabled:

@@ -47,33 +47,34 @@ def kv_write(cache: KVCache, k_new, v_new, start, pos_new=None) -> KVCache:
     its own offset).  ``pos_new`` optionally overrides the stored positions
     with an explicit ``(b, t)`` array — pad slots marked ``-1`` there are
     invalid and mask themselves out of attention and selection scoring."""
-    b, t = k_new.shape[:2]
-    s = jnp.asarray(start, jnp.int32)
-    pos_new = _pos_rows(s, t, b) if pos_new is None \
-        else jnp.asarray(pos_new, jnp.int32)
-    if s.ndim == 0:
-        z = jnp.zeros((), jnp.int32)
-        return KVCache(
-            k=jax.lax.dynamic_update_slice(cache.k,
-                                           k_new.astype(cache.k.dtype),
-                                           (z, s, z, z)),
-            v=jax.lax.dynamic_update_slice(cache.v,
-                                           v_new.astype(cache.v.dtype),
-                                           (z, s, z, z)),
-            pos=jax.lax.dynamic_update_slice(cache.pos, pos_new, (z, s)),
-        )
+    with jax.named_scope("kv_write"):
+        b, t = k_new.shape[:2]
+        s = jnp.asarray(start, jnp.int32)
+        pos_new = _pos_rows(s, t, b) if pos_new is None \
+            else jnp.asarray(pos_new, jnp.int32)
+        if s.ndim == 0:
+            z = jnp.zeros((), jnp.int32)
+            return KVCache(
+                k=jax.lax.dynamic_update_slice(cache.k,
+                                               k_new.astype(cache.k.dtype),
+                                               (z, s, z, z)),
+                v=jax.lax.dynamic_update_slice(cache.v,
+                                               v_new.astype(cache.v.dtype),
+                                               (z, s, z, z)),
+                pos=jax.lax.dynamic_update_slice(cache.pos, pos_new, (z, s)),
+            )
 
-    def row(kb, vb, pb, kn, vn, pn, si):
-        z = jnp.zeros((), jnp.int32)
-        return (jax.lax.dynamic_update_slice(kb, kn.astype(kb.dtype),
-                                             (si, z, z)),
-                jax.lax.dynamic_update_slice(vb, vn.astype(vb.dtype),
-                                             (si, z, z)),
-                jax.lax.dynamic_update_slice(pb, pn, (si,)))
+        def row(kb, vb, pb, kn, vn, pn, si):
+            z = jnp.zeros((), jnp.int32)
+            return (jax.lax.dynamic_update_slice(kb, kn.astype(kb.dtype),
+                                                 (si, z, z)),
+                    jax.lax.dynamic_update_slice(vb, vn.astype(vb.dtype),
+                                                 (si, z, z)),
+                    jax.lax.dynamic_update_slice(pb, pn, (si,)))
 
-    k2, v2, p2 = jax.vmap(row)(cache.k, cache.v, cache.pos,
-                               k_new, v_new, pos_new, s)
-    return KVCache(k=k2, v=v2, pos=p2)
+        k2, v2, p2 = jax.vmap(row)(cache.k, cache.v, cache.pos,
+                                   k_new, v_new, pos_new, s)
+        return KVCache(k=k2, v=v2, pos=p2)
 
 
 def kv_write_ring(cache: KVCache, k_new, v_new, start, pos_new=None) -> KVCache:
@@ -81,17 +82,18 @@ def kv_write_ring(cache: KVCache, k_new, v_new, start, pos_new=None) -> KVCache:
     wrap; a scatter over per-token slots handles it with static shapes.
     ``start`` must be a (possibly traced) scalar — windowed layers are not
     part of the paged/continuous path."""
-    b, t = k_new.shape[:2]
-    cap = cache.capacity
-    offs = jnp.arange(t, dtype=jnp.int32)
-    slots = (jnp.asarray(start, jnp.int32) + offs) % cap          # (t,)
-    pos_new = _pos_rows(start, t, b) if pos_new is None \
-        else jnp.asarray(pos_new, jnp.int32)
-    return KVCache(
-        k=cache.k.at[:, slots].set(k_new.astype(cache.k.dtype)),
-        v=cache.v.at[:, slots].set(v_new.astype(cache.v.dtype)),
-        pos=cache.pos.at[:, slots].set(pos_new),
-    )
+    with jax.named_scope("kv_write"):
+        b, t = k_new.shape[:2]
+        cap = cache.capacity
+        offs = jnp.arange(t, dtype=jnp.int32)
+        slots = (jnp.asarray(start, jnp.int32) + offs) % cap          # (t,)
+        pos_new = _pos_rows(start, t, b) if pos_new is None \
+            else jnp.asarray(pos_new, jnp.int32)
+        return KVCache(
+            k=cache.k.at[:, slots].set(k_new.astype(cache.k.dtype)),
+            v=cache.v.at[:, slots].set(v_new.astype(cache.v.dtype)),
+            pos=cache.pos.at[:, slots].set(pos_new),
+        )
 
 
 class LatentCache(NamedTuple):
@@ -117,30 +119,33 @@ def latent_write(cache: LatentCache, ckv_new, krope_new, start,
                  pos_new=None) -> LatentCache:
     """MLA twin of ``kv_write``: same scalar-or-per-row ``start`` and
     optional explicit ``pos_new`` semantics."""
-    b, t = ckv_new.shape[:2]
-    s = jnp.asarray(start, jnp.int32)
-    pos_new = _pos_rows(s, t, b) if pos_new is None \
-        else jnp.asarray(pos_new, jnp.int32)
-    if s.ndim == 0:
-        z = jnp.zeros((), jnp.int32)
-        return LatentCache(
-            ckv=jax.lax.dynamic_update_slice(cache.ckv,
-                                             ckv_new.astype(cache.ckv.dtype),
-                                             (z, s, z)),
-            krope=jax.lax.dynamic_update_slice(
-                cache.krope, krope_new.astype(cache.krope.dtype), (z, s, z)),
-            pos=jax.lax.dynamic_update_slice(cache.pos, pos_new, (z, s)),
-        )
+    with jax.named_scope("kv_write"):
+        b, t = ckv_new.shape[:2]
+        s = jnp.asarray(start, jnp.int32)
+        pos_new = _pos_rows(s, t, b) if pos_new is None \
+            else jnp.asarray(pos_new, jnp.int32)
+        if s.ndim == 0:
+            z = jnp.zeros((), jnp.int32)
+            return LatentCache(
+                ckv=jax.lax.dynamic_update_slice(
+                    cache.ckv, ckv_new.astype(cache.ckv.dtype), (z, s, z)),
+                krope=jax.lax.dynamic_update_slice(
+                    cache.krope, krope_new.astype(cache.krope.dtype),
+                    (z, s, z)),
+                pos=jax.lax.dynamic_update_slice(cache.pos, pos_new, (z, s)),
+            )
 
-    def row(cb, rb, pb, cn, rn, pn, si):
-        z = jnp.zeros((), jnp.int32)
-        return (jax.lax.dynamic_update_slice(cb, cn.astype(cb.dtype), (si, z)),
-                jax.lax.dynamic_update_slice(rb, rn.astype(rb.dtype), (si, z)),
-                jax.lax.dynamic_update_slice(pb, pn, (si,)))
+        def row(cb, rb, pb, cn, rn, pn, si):
+            z = jnp.zeros((), jnp.int32)
+            return (jax.lax.dynamic_update_slice(cb, cn.astype(cb.dtype),
+                                                 (si, z)),
+                    jax.lax.dynamic_update_slice(rb, rn.astype(rb.dtype),
+                                                 (si, z)),
+                    jax.lax.dynamic_update_slice(pb, pn, (si,)))
 
-    c2, r2, p2 = jax.vmap(row)(cache.ckv, cache.krope, cache.pos,
-                               ckv_new, krope_new, pos_new, s)
-    return LatentCache(ckv=c2, krope=r2, pos=p2)
+        c2, r2, p2 = jax.vmap(row)(cache.ckv, cache.krope, cache.pos,
+                                   ckv_new, krope_new, pos_new, s)
+        return LatentCache(ckv=c2, krope=r2, pos=p2)
 
 
 class MambaCache(NamedTuple):

@@ -113,13 +113,20 @@ class Engine:
         are token-identical to the meshless engine
         (tests/test_sharded_serving.py).
 
-        ``registry`` (repro.obs.Registry) turns on serve-path telemetry:
-        step spans, scheduler/pool counters, and the in-jit per-layer
-        selection stats (the step functions compile WITH the LayerObs
-        aux outputs — extra jit outputs, no host callbacks; with no
-        registry they compile without them, so the metrics-off compute is
-        bit-identical to pre-telemetry behavior).  ``Engine.stats`` is a
-        view of this registry either way (an ephemeral one when off)."""
+        ``registry`` (repro.obs.Registry) turns on serve-path host
+        telemetry: the ``engine/*`` phase spans of each step, the
+        ``request/*`` spans of each request's life, scheduler/pool
+        counters.  Host telemetry follows whatever registry
+        ``self.registry`` holds when a step runs, so one may be attached
+        after construction.  Whether the step functions compile WITH the
+        in-jit per-layer selection stats (the LayerObs aux outputs — extra
+        jit outputs, no host callbacks; without them the compute is
+        bit-identical to pre-telemetry behavior) is decided here, once:
+        they do when a registry is passed to the constructor
+        (``self.layer_obs``), and a registry attached later gets host
+        telemetry only.
+        ``Engine.stats`` is a view of the registry either way (an
+        ephemeral one when off)."""
         from repro.kernels import ops as kops
         self.model = model
         self.mesh = mesh
@@ -146,7 +153,7 @@ class Engine:
                 "backend 'xla' / 'pallas_interpret' for parity runs")
         self.sampler = sampler
         self.registry = registry if registry is not None else obs_reg.NULL
-        self._obs_on = bool(self.registry.enabled)
+        self.layer_obs = bool(self.registry.enabled)
         self.stats: Dict[str, float] = {}   # prefix-cache stats of last serve
         self._warmed: set = set()           # generate() jit-warmup signatures
         donate = {}
@@ -318,11 +325,11 @@ class Engine:
         mesh = self.mesh
         chunk = model.cfg.quoka.chunk_size
         sampler = self.sampler
-        # compiled-in telemetry: with a live registry the step fns return
+        # compiled-in telemetry: with ``layer_obs`` the step fns return
         # the per-layer LayerObs pytree as an EXTRA jit output (device
-        # scalars, fetched alongside the sampled tokens); without one they
+        # scalars, fetched alongside the sampled tokens); without it they
         # compile exactly as before — bit-identical metrics-off compute
-        obs_on = self._obs_on
+        obs_on = self.layer_obs
         selb = (block_size, max_nb) if sel_on else None
 
         if mesh is not None:
@@ -497,13 +504,12 @@ class Engine:
     def _record_layer_obs(self, phase: str, lobs) -> None:
         """Feed one step's in-jit ``LayerObs`` pytree (per-layer device
         scalars, core/plan.py) into the registry: per-layer selected-KV
-        fraction vs the budget ratio, plan refresh/reuse counts, and the
-        score-distribution sketch.  NaN marks not-applicable (non-selecting
-        blocks; budget/sketch on dense-fallback layers; sketch on plan-reuse
-        steps) and is skipped.  One stacked host transfer per step."""
+        fraction vs the budget ratio and plan refresh/reuse counts.  NaN
+        marks not-applicable (non-selecting blocks; budget on
+        dense-fallback layers) and is skipped.  One stacked host transfer
+        per step."""
         reg = self.registry
-        sel, ctx, bud, ref, lo, mean, hi = np.asarray(
-            jnp.stack(lobs))                           # (7, n_layers)
+        sel, ctx, bud, ref = np.asarray(jnp.stack(lobs))   # (4, n_layers)
         with np.errstate(invalid="ignore", divide="ignore"):
             frac = sel / ctx
             budf = bud / ctx
@@ -520,11 +526,6 @@ class Engine:
             n_ref = float(fin.sum())
             reg.count("select/plan_refresh", n_ref)
             reg.count("select/plan_reuse", float(fin.size) - n_ref)
-        for nm, v in (("score_lo", lo), ("score_mean", mean),
-                      ("score_hi", hi)):
-            v = v[np.isfinite(v)]
-            if v.size:
-                reg.observe(f"select/{nm}", float(v.mean()))
 
     def _note_hot(self, state: ServeState, sel, rows: int) -> None:
         """Fold one step's selection counts ((rows_compiled, max_nb) int32,
@@ -587,37 +588,49 @@ class Engine:
         decode step over every active decode request.  Returns
         (prefill rows, decode rows) executed.  ``state.events`` is reset and
         filled with this step's emitted (rid, token) pairs — the feed
-        ``serve_stream`` yields from."""
+        ``serve_stream`` yields from.
+
+        With a registry attached, each host phase is a span:
+        ``engine/admit`` (admission and prefill packing), then per step
+        program ``engine/inputs`` (its host arrays, block table and key),
+        ``engine/prefill_step`` / ``engine/decode_step`` (dispatch through
+        the blocking token fetch, ``engine/fetch`` nested) and
+        ``engine/emit`` (the tokens handed to the scheduler, the
+        ``request/*`` spans of requests that reached their first token or
+        finished)."""
         pool, sched = state.pool, state.sched
-        reg, obs = self.registry, self._obs_on
+        reg, host, obs = self.registry, self.registry.enabled, self.layer_obs
         state.events = []
-        admitted = sched.admit(state.now)
-        if obs:
-            now = state.now
-            for r in admitted:
-                reg.observe("sched/admission_wait_s",
-                            max(0.0, now - r.arrival_s))
-            reg.set("sched/queue_depth", float(len(sched.waiting)))
-            reg.set("sched/suspended", float(len(sched.suspended)))
-            reg.set("sched/active", float(sched.n_active))
-            reg.set("pool/occupancy", 1.0 - pool.num_free / pool.num_blocks)
-            reg.set("pool/cached_blocks", float(pool.num_cached))
+        with reg.span("engine/admit"):
+            admitted = sched.admit(state.now)
+            if host:
+                now = state.now
+                for r in admitted:
+                    reg.observe("sched/admission_wait_s",
+                                max(0.0, now - r.arrival_s))
+                reg.set("sched/queue_depth", float(len(sched.waiting)))
+                reg.set("sched/suspended", float(len(sched.suspended)))
+                reg.set("sched/active", float(sched.n_active))
+                reg.set("pool/occupancy",
+                        1.0 - pool.num_free / pool.num_blocks)
+                reg.set("pool/cached_blocks", float(pool.num_cached))
+                if pool.host is not None:
+                    reg.set("pool/host_blocks", float(len(pool.host)))
             if pool.host is not None:
-                reg.set("pool/host_blocks", float(len(pool.host)))
-        if pool.host is not None:
-            self._host_counters(state)
+                self._host_counters(state)
+            rows = sched.pack_prefill(state.now)
         sel_at = 2 + (1 if obs else 0)     # extra-output slot (host tier)
 
-        rows = sched.pack_prefill(state.now)
         if rows:
-            tokens = np.zeros((state.b_prefill, state.chunk), np.int32)
-            start = np.zeros((state.b_prefill,), np.int32)
-            vlen = np.zeros((state.b_prefill,), np.int32)
-            for i, (r, ch, st, vl) in enumerate(rows):
-                tokens[i], start[i], vlen[i] = ch, st, vl
-            table = pool.table_array([r.rid for r, *_ in rows],
-                                     state.b_prefill, state.max_nb)
-            state.key, k1 = jax.random.split(state.key)
+            with reg.span("engine/inputs"):
+                tokens = np.zeros((state.b_prefill, state.chunk), np.int32)
+                start = np.zeros((state.b_prefill,), np.int32)
+                vlen = np.zeros((state.b_prefill,), np.int32)
+                for i, (r, ch, st, vl) in enumerate(rows):
+                    tokens[i], start[i], vlen[i] = ch, st, vl
+                table = pool.table_array([r.rid for r, *_ in rows],
+                                         state.b_prefill, state.max_nb)
+                state.key, k1 = jax.random.split(state.key)
             # the span brackets dispatch THROUGH the token fetch: with the
             # async runtime the np.asarray sync is where device time lands
             with reg.span("engine/prefill_step", rows=len(rows)):
@@ -628,45 +641,56 @@ class Engine:
                 # step dispatch and the blocking token fetch, so the copies
                 # run under the compute this step already queued
                 self._prefetch(state)
-                tok_np = np.asarray(tok)
-            if obs:
-                self._record_layer_obs("prefill", out[2])
-                reg.count("engine/prefill_tokens", float(vlen.sum()))
-            if state.host_tier:
-                self._note_hot(state, out[sel_at], len(rows))
-            now = state.now
-            for i, (r, ch, st, vl) in enumerate(rows):
-                ev = sched.note_prefilled(r, vl, int(tok_np[i]), now)
-                if ev is not None:
-                    state.events.append((r.rid, ev))
+                with reg.span("engine/fetch"):
+                    tok_np = np.asarray(tok)
+            with reg.span("engine/emit"):
+                if obs:
+                    self._record_layer_obs("prefill", out[2])
+                if host:
+                    reg.count("engine/prefill_tokens", float(vlen.sum()))
+                if state.host_tier:
+                    self._note_hot(state, out[sel_at], len(rows))
+                now = state.now
+                for i, (r, ch, st, vl) in enumerate(rows):
+                    ev = sched.note_prefilled(r, vl, int(tok_np[i]), now)
+                    if ev is not None:
+                        state.events.append((r.rid, ev))
+                        if host:
+                            self._record_request(state, r, first=True)
             state.prefill_steps += 1
 
         drows = sched.pack_decode()
         if drows:
-            tokens = np.zeros((state.b_decode,), np.int32)
-            pos = np.zeros((state.b_decode,), np.int32)
-            live = np.zeros((state.b_decode,), np.int32)
-            for i, r in enumerate(drows):
-                tokens[i], pos[i], live[i] = r.out[-1], r.decode_pos, 1
-            table = pool.table_array([r.rid for r in drows],
-                                     state.b_decode, state.max_nb)
-            state.key, k2 = jax.random.split(state.key)
+            with reg.span("engine/inputs"):
+                tokens = np.zeros((state.b_decode,), np.int32)
+                pos = np.zeros((state.b_decode,), np.int32)
+                live = np.zeros((state.b_decode,), np.int32)
+                for i, r in enumerate(drows):
+                    tokens[i], pos[i], live[i] = r.out[-1], r.decode_pos, 1
+                table = pool.table_array([r.rid for r in drows],
+                                         state.b_decode, state.max_nb)
+                state.key, k2 = jax.random.split(state.key)
             with reg.span("engine/decode_step", rows=len(drows)):
                 out = self._call(state.fns[1], self.params, pool.data,
                                  table, tokens, pos, live, k2)
                 pool.data, tok = out[0], out[1]
                 self._prefetch(state)
-                tok_np = np.asarray(tok)
-            if obs:
-                self._record_layer_obs("decode", out[2])
-                reg.count("engine/decode_tokens", float(len(drows)))
-            if state.host_tier:
-                self._note_hot(state, out[sel_at], len(drows))
-            now = state.now
-            for i, r in enumerate(drows):
-                state.events.append((r.rid,
-                                     sched.note_decoded(r, int(tok_np[i]),
-                                                        now)))
+                with reg.span("engine/fetch"):
+                    tok_np = np.asarray(tok)
+            with reg.span("engine/emit"):
+                if obs:
+                    self._record_layer_obs("decode", out[2])
+                if host:
+                    reg.count("engine/decode_tokens", float(len(drows)))
+                if state.host_tier:
+                    self._note_hot(state, out[sel_at], len(drows))
+                now = state.now
+                for i, r in enumerate(drows):
+                    state.events.append((r.rid,
+                                         sched.note_decoded(r, int(tok_np[i]),
+                                                            now)))
+                    if host and r.done_s is not None:
+                        self._record_request(state, r, first=False)
             # occupancy over the SCHEDULER's slot bound (the compiled row
             # batch may carry mesh-rounding padding rows)
             state.occupancy.append(len(drows) / sched.max_decode_batch)
@@ -674,6 +698,23 @@ class Engine:
 
         state.steps += 1
         return len(rows), len(drows)
+
+    def _record_request(self, state: ServeState, r, first: bool) -> None:
+        """The ``request/*`` spans of ``r``, each with ``rid=``: at its first
+        token ``request/queued`` (due -> admitted) and ``request/prefill``
+        (admitted -> first token); once it is done ``request/decode``
+        (first token -> done).  The request's times are on the serve
+        clock, offsets from ``state.t0``; the registry takes them as
+        ``perf_counter`` readings."""
+        reg, t0 = self.registry, state.t0
+        first_s = t0 + r.arrival_s + r.ttft_s
+        if first:
+            admitted = t0 + r.admitted_s
+            reg.record("request/queued", t0 + r.arrival_s, admitted,
+                       rid=r.rid)
+            reg.record("request/prefill", admitted, first_s, rid=r.rid)
+        if r.done_s is not None:
+            reg.record("request/decode", first_s, t0 + r.done_s, rid=r.rid)
 
     def serve_stream(self, requests: Sequence, *,
                      block_size: Optional[int] = None,
@@ -762,7 +803,9 @@ class Engine:
                 # drift and keeps shutdown/interrupt latency sane.  Step
                 # counts are untouched — only wakeups that packed nothing
                 # are skipped (tests/test_scheduler.py asserts both).
-                time.sleep(min(0.25, max(0.0, pending[0].arrival_s - now)))
+                with self.registry.span("engine/idle_wait"):
+                    time.sleep(min(0.25,
+                                   max(0.0, pending[0].arrival_s - now)))
                 continue
             n_pf, n_dec = self.step(state)
             if n_pf == 0 and n_dec == 0 and sched.pending():
@@ -785,7 +828,8 @@ class Engine:
         # calls on one engine, while these are deltas of THIS trace) and are
         # read back as a flat suffix-keyed dict.  With metrics off an
         # ephemeral registry keeps the public dict shape identical.
-        preg = self.registry if self._obs_on else obs_reg.Registry()
+        preg = self.registry if self.registry.enabled \
+            else obs_reg.Registry()
         sc = preg.scope("serve/prefix")
         sc.set("requests", pool.lookups - prefix0[0])
         sc.set("cache_hits", pool.hit_requests - prefix0[1])
@@ -802,7 +846,7 @@ class Engine:
             sc.set("staged_used", pool.staged_used - prefix0[9])
             sc.set("host_blocks", len(pool.host))
         self.stats = preg.view("serve/prefix")
-        if self._obs_on:
+        if self.registry.enabled:
             reg = self.registry
             for r in done:
                 if r.ttft_s is not None:

@@ -884,10 +884,12 @@ def scatter(data, gathered, table, touched, num_blocks: int,
 
 def touched_blocks(slot, n_tokens, max_nb: int, block_size: int):
     """(b, max_nb) bool: logical blocks covered by a write of ``n_tokens``
-    rows starting at ``slot`` (both (b,) int32; n_tokens == 0 -> none)."""
-    slot = jnp.asarray(slot, jnp.int32)
-    n = jnp.asarray(n_tokens, jnp.int32)
-    lo = slot // block_size
-    hi = (slot + jnp.maximum(n, 1) - 1) // block_size
-    ar = jnp.arange(max_nb, dtype=jnp.int32)[None]
-    return (ar >= lo[:, None]) & (ar <= hi[:, None]) & (n > 0)[:, None]
+    rows starting at ``slot`` (both (b,) int32; n_tokens == 0 -> none):
+    the scatter's write mask, so it carries the scatter's scope."""
+    with jax.named_scope("pool_scatter"):
+        slot = jnp.asarray(slot, jnp.int32)
+        n = jnp.asarray(n_tokens, jnp.int32)
+        lo = slot // block_size
+        hi = (slot + jnp.maximum(n, 1) - 1) // block_size
+        ar = jnp.arange(max_nb, dtype=jnp.int32)[None]
+        return (ar >= lo[:, None]) & (ar <= hi[:, None]) & (n > 0)[:, None]

@@ -50,6 +50,8 @@ class Request:
     cached_len: int = 0             # prompt tokens served from the prefix
                                     # cache at admission (never recomputed)
     out: List[int] = field(default_factory=list)   # generated tokens
+    admitted_s: Optional[float] = None   # first admission, on the
+                                         # engine's clock like arrival_s
     ttft_s: Optional[float] = None
     done_s: Optional[float] = None
     preemptions: int = 0            # times suspended (policy decision)

@@ -17,6 +17,11 @@ class SamplerConfig:
 
 def sample(logits, key, cfg: SamplerConfig):
     """logits: (b, V) fp32 -> (b,) int32."""
+    with jax.named_scope("sample"):
+        return _sample(logits, key, cfg)
+
+
+def _sample(logits, key, cfg: SamplerConfig):
     if cfg.temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     lg = logits / cfg.temperature

@@ -133,6 +133,7 @@ class Scheduler:
         r.n_prefilled = 0
         r.cached_len = 0
         r.out = []
+        r.admitted_s = None
         r.ttft_s = None
         r.done_s = None
         r.preemptions = 0
@@ -273,6 +274,7 @@ class Scheduler:
             pool.hit_tokens += cached
         r.cached_len = cached
         r.n_prefilled = cached         # prefill only the uncached suffix
+        r.admitted_s = now
         r.status = rq.PREFILL
         self.waiting.remove(r)
         self.prefilling.append(r)
