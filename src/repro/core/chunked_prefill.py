@@ -85,11 +85,11 @@ def chunked_sparse_attention(q, k, v, cfg: QuokaConfig,
         # prefix (every gathered key is strictly before the chunk by
         # construction), the chunk is causal w.r.t. chunk-local indices —
         # exactly the flash kernel's static `boundary` mask, with budget
-        # padding masked via per-KV-head k_valid (sel.pos == -1).
+        # padding masked via per-KV-head k_valid (``sel.valid``).
         k_cat = jnp.concatenate([sel.k, kc], axis=1)
         v_cat = jnp.concatenate([sel.v, vc], axis=1)
         k_valid = jnp.concatenate(
-            [sel.pos >= 0, jnp.ones((b, n_kv, bcp), bool)], axis=-1)
+            [sel.valid, jnp.ones((b, n_kv, bcp), bool)], axis=-1)
         return kops.attention(qc, k_cat, v_cat, k_valid, causal=True,
                               boundary=sel.pos.shape[-1], backend=backend)
 

@@ -186,15 +186,22 @@ def _plan_from_scores(scores, key_pos, cfg, budget):
 # ----------------------------------------------------------------------------
 
 def materialize(plan: SelectionPlan, k, v, key_pos, chunk_start,
-                cfg: QuokaConfig) -> Selected:
+                cfg: QuokaConfig, fresh: bool = False) -> Selected:
     """Gather a plan's KV budget from a cache view into a dense ``Selected``.
 
-    k, v: (b, T, n_kv, d); key_pos: (b, T).  Validity is re-derived HERE
-    (``prior_context_valid``), not trusted from build time: block-granular
-    plans include boundary-straddling blocks whole and reused plans may be
-    consumed under a different query chunk, so per-token selectability is a
-    materialize-time property.  Tokens that are not selectable get
-    ``pos == -1`` (budget padding), which downstream attention masks.
+    k, v: (b, T, n_kv, d); key_pos: (b, T).  Tokens that are not
+    selectable get ``idx == pos == -1`` (budget padding), which downstream
+    attention masks through ``Selected.valid``.
+
+    Validity is taken from the plan when the plan was built under the same
+    ``(key_pos, chunk_start)`` — ``fresh`` — at granularity 1: scoring puts
+    NEG_INF on every non-selectable slot and ``plan_from_scores`` writes -1
+    there, so ``idx >= 0`` already means selectable and no position gather
+    is needed for it.  Otherwise it is re-derived elementwise from the
+    gathered positions (``prior_context_valid``): block-granular plans
+    include boundary-straddling blocks whole and reused plans may be
+    consumed under a different query chunk.  The validity of the cache view
+    itself is never gathered.
 
     At granularity g > 1 the gather moves whole (g, n_kv, d) slabs via a
     block-axis ``take_along_axis`` — XLA lowers this to a gather whose
@@ -202,30 +209,44 @@ def materialize(plan: SelectionPlan, k, v, key_pos, chunk_start,
     blocks, no per-token gather), the property the paged serving path
     relies on and the HLO suite asserts.
     """
-    b, t, n_kv, d = k.shape
     g = grid(cfg)
     with jax.named_scope("plan_materialize"):
-        return _materialize(plan, k, v, key_pos, chunk_start, cfg, b, t,
-                            n_kv, d, g)
+        return _materialize(plan, k, v, key_pos, chunk_start, g, fresh)
 
 
-def _materialize(plan, k, v, key_pos, chunk_start, cfg, b, t, n_kv, d, g):
-    valid = prior_context_valid(key_pos, chunk_start)            # (b, T)
+def _slot_positions(plan, key_pos, chunk_start, g, fresh):
+    """(positions, selectable) of every planned slot, in the plan's layout:
+    (b, n_kv, B) at g == 1, (b, NB, g) at g > 1; position -1 where the
+    slot is not selectable."""
+    b, t = key_pos.shape
     if g == 1:
         top_i = plan.idx                                         # (b,n_kv,B)
-        safe = jnp.maximum(top_i, 0)
-        # gather along the TIME axis directly — transposing the K/V caches
-        # first would materialise a full-cache copy per chunk (§Perf A5)
-        idx_t = safe.transpose(0, 2, 1)[..., None]               # (b,B,n_kv,1)
-        k_sel = jnp.take_along_axis(k, idx_t, axis=1)            # (b,B,n_kv,d)
-        v_sel = jnp.take_along_axis(v, idx_t, axis=1)
         shape = top_i.shape[:2] + (t,)
         pos = jnp.take_along_axis(
-            jnp.broadcast_to(key_pos[:, None, :], shape), safe, axis=2)
-        ok = jnp.take_along_axis(
-            jnp.broadcast_to(valid[:, None, :], shape), safe, axis=2)
-        good = (top_i >= 0) & ok
-        return Selected(k=k_sel, v=v_sel, pos=jnp.where(good, pos, -1),
+            jnp.broadcast_to(key_pos[:, None, :], shape),
+            jnp.maximum(top_i, 0), axis=2)
+        good = top_i >= 0
+    else:
+        pos = jnp.take_along_axis(key_pos.reshape(b, t // g, g),
+                                  jnp.maximum(plan.idx, 0)[:, :, None],
+                                  axis=1)                        # (b, NB, g)
+        good = (plan.idx >= 0)[:, :, None]
+    if not (fresh and g == 1):
+        good = good & prior_context_valid(pos, chunk_start)
+    return jnp.where(good, pos, -1), good
+
+
+def _materialize(plan, k, v, key_pos, chunk_start, g, fresh):
+    b, t, n_kv, d = k.shape
+    pos, good = _slot_positions(plan, key_pos, chunk_start, g, fresh)
+    if g == 1:
+        top_i = plan.idx                                         # (b,n_kv,B)
+        # gather along the TIME axis directly — transposing the K/V caches
+        # first would materialise a full-cache copy per chunk (§Perf A5)
+        idx_t = jnp.maximum(top_i, 0).transpose(0, 2, 1)[..., None]
+        k_sel = jnp.take_along_axis(k, idx_t, axis=1)            # (b,B,n_kv,d)
+        v_sel = jnp.take_along_axis(v, idx_t, axis=1)
+        return Selected(k=k_sel, v=v_sel, pos=pos,
                         idx=jnp.where(good, top_i, -1))
     nb = plan.idx.shape[1]
     blocks = jnp.maximum(plan.idx, 0)                            # (b, NB)
@@ -234,19 +255,13 @@ def _materialize(plan, k, v, key_pos, chunk_start, cfg, b, t, n_kv, d, g):
     k_sel = jnp.take_along_axis(kb, ib, axis=1).reshape(b, nb * g, n_kv, d)
     v_sel = jnp.take_along_axis(v.reshape(b, t // g, g, n_kv, d), ib,
                                 axis=1).reshape(b, nb * g, n_kv, d)
-    pos_sel = jnp.take_along_axis(key_pos.reshape(b, t // g, g),
-                                  blocks[:, :, None], axis=1)    # (b, NB, g)
-    ok_sel = jnp.take_along_axis(valid.reshape(b, t // g, g),
-                                 blocks[:, :, None], axis=1)
-    good = ok_sel & (plan.idx >= 0)[:, :, None]
-    pos_flat = jnp.where(good, pos_sel, -1).reshape(b, nb * g)
     slot = blocks[:, :, None] * g + jnp.arange(g, dtype=jnp.int32)
     idx_flat = jnp.where(good, slot, -1).reshape(b, nb * g)
     # heads share the plan: broadcast the per-token metadata to the
     # Selected contract's per-head layout
     return Selected(
         k=k_sel, v=v_sel,
-        pos=jnp.broadcast_to(pos_flat[:, None, :], (b, n_kv, nb * g)),
+        pos=jnp.broadcast_to(pos.reshape(b, 1, nb * g), (b, n_kv, nb * g)),
         idx=jnp.broadcast_to(idx_flat[:, None, :], (b, n_kv, nb * g)))
 
 
@@ -291,7 +306,7 @@ def select(method: str, q, k, v, key_pos, chunk_start, cfg: QuokaConfig,
     (``full`` must be handled by the caller — it means 'do not select')."""
     pln = build(method, q, k, key_pos, chunk_start, cfg, budget=budget,
                 q_valid=q_valid)
-    return materialize(pln, k, v, key_pos, chunk_start, cfg)
+    return materialize(pln, k, v, key_pos, chunk_start, cfg, fresh=True)
 
 
 # ----------------------------------------------------------------------------
@@ -395,11 +410,12 @@ def select_with_ctx(ctx, plan, method: str, q, k, v, key_pos, chunk_start,
     li = ctx.get("layer_idx", 0)
     obs = ctx.get("obs")
     refreshed = refreshed_flag(plan, li, cfg) if obs else None
+    fresh = plan is None              # no carry: refresh builds right here
     pln, plan = refresh(
         plan, li, cfg,
         lambda: build(method, q, k, key_pos, chunk_start, cfg,
                       budget=budget, q_valid=q_valid))
-    sel = materialize(pln, k, v, key_pos, chunk_start, cfg)
+    sel = materialize(pln, k, v, key_pos, chunk_start, cfg, fresh=fresh)
     if obs:
         t = k.shape[1]
         bud = floor_to_grid(
@@ -484,30 +500,15 @@ def fused_route(cfg: QuokaConfig, method: str, k,
 def plan_selected_pos(plan: SelectionPlan, key_pos, chunk_start,
                       cfg: QuokaConfig) -> jax.Array:
     """Positions-only twin of ``materialize`` for telemetry: the selected
-    positions (-1 = padding) with validity re-derived exactly as
-    materialize derives it, WITHOUT touching K/V.  The fused kernel applies
-    the same masks in-kernel; this keeps ``LayerObs.sel_tokens`` exact
-    while gathering only the (b, T) int32 positions — bytes, not the KV
-    budget the fused path exists to avoid."""
-    b, t = key_pos.shape
+    positions (-1 = padding), WITHOUT touching K/V.  Validity is always
+    re-derived elementwise from the gathered positions, as materialize
+    does for plans it cannot take it from.  The fused kernel applies the
+    same masks in-kernel; this keeps ``LayerObs.sel_tokens`` exact while
+    gathering only the (b, T) int32 positions — bytes, not the KV budget
+    the fused path exists to avoid."""
     g = grid(cfg)
-    valid = prior_context_valid(key_pos, chunk_start)
-    if g == 1:
-        top_i = plan.idx                                     # (b, n_kv, B)
-        safe = jnp.maximum(top_i, 0)
-        shape = top_i.shape[:2] + (t,)
-        pos = jnp.take_along_axis(
-            jnp.broadcast_to(key_pos[:, None, :], shape), safe, axis=2)
-        ok = jnp.take_along_axis(
-            jnp.broadcast_to(valid[:, None, :], shape), safe, axis=2)
-        return jnp.where((top_i >= 0) & ok, pos, -1)
-    blocks = jnp.maximum(plan.idx, 0)                        # (b, NB)
-    pos_sel = jnp.take_along_axis(key_pos.reshape(b, t // g, g),
-                                  blocks[:, :, None], axis=1)
-    ok_sel = jnp.take_along_axis(valid.reshape(b, t // g, g),
-                                 blocks[:, :, None], axis=1)
-    good = ok_sel & (plan.idx >= 0)[:, :, None]
-    return jnp.where(good, pos_sel, -1).reshape(b, 1, -1)
+    pos, _ = _slot_positions(plan, key_pos, chunk_start, g, fresh=False)
+    return pos if g == 1 else pos.reshape(key_pos.shape[0], 1, -1)
 
 
 def fused_attend_with_ctx(ctx, plan, method: str, q, k, v, key_pos,

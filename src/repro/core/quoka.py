@@ -36,12 +36,23 @@ from repro.sharding import ctx as shctx
 
 
 class Selected(NamedTuple):
-    """A gathered KV budget.  Positions are per-KV-head (b, n_kv, B);
-    -1 marks padding (fewer valid KVs than the budget)."""
+    """A gathered KV budget.  Positions are per-KV-head (b, n_kv, B).
+
+    Validity contract: ``idx >= 0`` (``valid``) marks an attendable slot;
+    -1 in ``idx`` marks padding (fewer valid KVs than the budget).  ``pos``
+    is -1 at exactly the same slots, but it is a gather of its own: read it
+    only where positions are needed as VALUES (sliding windows, telemetry,
+    recall analysis) — callers that only mask read ``valid``, and under jit
+    the unread position gather is dead code."""
     k: jax.Array          # (b, B, n_kv, d)
     v: jax.Array          # (b, B, n_kv, d)
     pos: jax.Array        # (b, n_kv, B) int32
-    idx: jax.Array        # (b, n_kv, B) int32 cache slots (for analysis)
+    idx: jax.Array        # (b, n_kv, B) int32 cache slots
+
+    @property
+    def valid(self) -> jax.Array:
+        """(b, n_kv, B) bool: which budget slots may be attended."""
+        return self.idx >= 0
 
 
 # ----------------------------------------------------------------------------
@@ -205,10 +216,11 @@ def prior_context_valid(key_pos: jax.Array, chunk_start) -> jax.Array:
 
     ``chunk_start`` may be a traced scalar (scan carry) or a per-row ``(b,)``
     vector (continuous batching: requests in one step batch sit at different
-    positions)."""
+    positions).  ``key_pos`` is (b, ...) with the batch axis first: the
+    cache view's (b, T) or positions already gathered from it."""
     cs = jnp.asarray(chunk_start, jnp.int32)
     if cs.ndim == 1:
-        cs = cs[:, None]
+        cs = cs.reshape(cs.shape + (1,) * (key_pos.ndim - 1))
     return (key_pos >= 0) & (key_pos < cs)
 
 
@@ -261,6 +273,10 @@ def tp_plan_candidates(qs: jax.Array, k: jax.Array, key_pos: jax.Array,
     ascending, local top-k orders ties by index, and the merge prefers
     earlier candidate positions), so the returned PLAN INDICES — and
     therefore decoding — are bit-identical to the meshless run.
+
+    Invalid slots score NEG_INF through the same facade and take -1 under
+    the same ``> NEG_INF / 2`` test, so a plan from here is as fresh as a
+    meshless one: materialize may read validity off its indices.
 
     Only indices leave the shard_map: the materialize stage runs outside,
     on the replicated caches (core/plan.py), so the same contiguous-gather

@@ -204,7 +204,7 @@ class AttnBlock:
                             backend=None):
         """Attention over [selected budget | current chunk] via the kernel
         facade: the budget is an unconditioned prefix (`boundary`), budget
-        padding is masked through per-KV-head `k_valid` (sel.pos == -1).
+        padding is masked through per-KV-head `k_valid` (``sel.valid``).
 
         Sliding-window layers keep the masked dense path — the window
         constraint on selected keys is per-QUERY and cannot be expressed by
@@ -217,7 +217,7 @@ class AttnBlock:
         # chunk keys with pos == -1 are pad slots — never attendable
         chunk_valid = jnp.broadcast_to((pos >= 0)[:, None, :], (b, n_kv, t))
         if self.window is None:
-            k_valid = jnp.concatenate([sel.pos >= 0, chunk_valid], axis=-1)
+            k_valid = jnp.concatenate([sel.valid, chunk_valid], axis=-1)
             return kops.attention(q, k_cat, v_cat, k_valid, causal=True,
                                   boundary=sel.pos.shape[-1],
                                   backend=backend, cfg=self.cfg.quoka)
@@ -418,7 +418,7 @@ class MLABlock:
         rd = k_cat.shape[-1] - r
         v_pad = jnp.pad(ckv_cat, ((0, 0), (0, 0), (0, rd)))[:, :, None, :]
         k_valid = jnp.concatenate(
-            [sel.pos >= 0, (pos >= 0)[:, None, :]], axis=-1)
+            [sel.valid, (pos >= 0)[:, None, :]], axis=-1)
         o_lat = kops.attention(q_score, k_cat, v_pad, k_valid, causal=True,
                                boundary=sel.pos.shape[-1], scale=self.scale,
                                backend=ctx.get("backend"), cfg=qc)[..., :r]

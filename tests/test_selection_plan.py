@@ -23,8 +23,10 @@ from repro.analysis import hlo
 from repro.configs import get_config
 from repro.configs.base import QuokaConfig
 from repro.core import plan as plan_mod
+from repro.core import selection as sel_scores
 from repro.core.attention import NEG_INF
 from repro.core.chunked_prefill import output_error
+from repro.core.quoka import Selected, prior_context_valid
 from repro.data.synthetic import structured_qkv
 from repro.models.model import build_model
 from repro.serving import pool as pl
@@ -122,6 +124,149 @@ def test_block_granularity_accuracy_delta_bounded():
     err_blk = float(output_error(q, k, v, blk, "quoka"))
     assert err_blk < 0.5, (err_tok, err_blk)
     assert err_blk <= err_tok + 0.15, (err_tok, err_blk)
+
+
+# ---------------------------------------------------------------------------
+# materialize's validity route == the four-gather oracle, bit for bit
+# ---------------------------------------------------------------------------
+
+def _oracle_materialize(plan, k, v, key_pos, chunk_start, cfg):
+    """The earlier materialize, kept verbatim as the oracle: it gathers the
+    positions AND the cache view's validity mask at every selected slot."""
+    b, t, n_kv, d = k.shape
+    g = plan_mod.grid(cfg)
+    valid = prior_context_valid(key_pos, chunk_start)            # (b, T)
+    if g == 1:
+        top_i = plan.idx                                         # (b,n_kv,B)
+        safe = jnp.maximum(top_i, 0)
+        idx_t = safe.transpose(0, 2, 1)[..., None]               # (b,B,n_kv,1)
+        k_sel = jnp.take_along_axis(k, idx_t, axis=1)            # (b,B,n_kv,d)
+        v_sel = jnp.take_along_axis(v, idx_t, axis=1)
+        shape = top_i.shape[:2] + (t,)
+        pos = jnp.take_along_axis(
+            jnp.broadcast_to(key_pos[:, None, :], shape), safe, axis=2)
+        ok = jnp.take_along_axis(
+            jnp.broadcast_to(valid[:, None, :], shape), safe, axis=2)
+        good = (top_i >= 0) & ok
+        return Selected(k=k_sel, v=v_sel, pos=jnp.where(good, pos, -1),
+                        idx=jnp.where(good, top_i, -1))
+    nb = plan.idx.shape[1]
+    blocks = jnp.maximum(plan.idx, 0)                            # (b, NB)
+    kb = k.reshape(b, t // g, g, n_kv, d)
+    ib = blocks[:, :, None, None, None]
+    k_sel = jnp.take_along_axis(kb, ib, axis=1).reshape(b, nb * g, n_kv, d)
+    v_sel = jnp.take_along_axis(v.reshape(b, t // g, g, n_kv, d), ib,
+                                axis=1).reshape(b, nb * g, n_kv, d)
+    pos_sel = jnp.take_along_axis(key_pos.reshape(b, t // g, g),
+                                  blocks[:, :, None], axis=1)    # (b, NB, g)
+    ok_sel = jnp.take_along_axis(valid.reshape(b, t // g, g),
+                                 blocks[:, :, None], axis=1)
+    good = ok_sel & (plan.idx >= 0)[:, :, None]
+    pos_flat = jnp.where(good, pos_sel, -1).reshape(b, nb * g)
+    slot = blocks[:, :, None] * g + jnp.arange(g, dtype=jnp.int32)
+    idx_flat = jnp.where(good, slot, -1).reshape(b, nb * g)
+    return Selected(
+        k=k_sel, v=v_sel,
+        pos=jnp.broadcast_to(pos_flat[:, None, :], (b, n_kv, nb * g)),
+        idx=jnp.broadcast_to(idx_flat[:, None, :], (b, n_kv, nb * g)))
+
+
+def _views(case):
+    """(key_pos (2, 64), chunk_start, q_valid, keep_first) per input kind.
+    Row 0 and row 1 differ in every kind so both layouts are exercised."""
+    t = 64
+    ar = np.arange(t, dtype=np.int32)
+    pos = np.stack([ar, ar])
+    start = np.asarray(48, np.int32)
+    q_valid = None
+    keep_first = 0
+    if case == "pads":              # left-pad slots and an empty cache tail
+        pos[0, :8] = -1
+        pos[0, 8:] = ar[:t - 8]
+        pos[1, 56:] = -1
+    elif case == "ragged":          # rows at different chunk starts
+        start = np.asarray([40, 56], np.int32)
+        q_valid = np.ones((2, 8), bool)
+        q_valid[1, 5:] = False
+    elif case == "holes":           # -1 stamps in the middle (prefix COW)
+        pos[0, 10:14] = -1
+        pos[0, 30] = -1
+        pos[1, 20:28] = -1
+    elif case == "short":           # fewer prior slots than the budget
+        start = np.asarray([20, 12], np.int32)
+    elif case == "sinks":           # sinks behind pads and next to a hole
+        keep_first = 4
+        pos[0, 1] = -1
+        pos[1, :5] = -1
+        pos[1, 5:] = ar[:t - 5]
+    else:
+        raise ValueError(case)
+    qv = None if q_valid is None else jnp.asarray(q_valid)
+    return jnp.asarray(pos), jnp.asarray(start), qv, keep_first
+
+
+def _assert_same(sel, ref):
+    for name in Selected._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(sel, name)),
+                                      np.asarray(getattr(ref, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(sel.valid),
+                                  np.asarray(ref.pos >= 0))
+
+
+@pytest.mark.parametrize("case", ["pads", "ragged", "holes", "short",
+                                  "sinks"])
+@pytest.mark.parametrize("method", [m for m in sel_scores.METHODS
+                                    if m != "full"])
+@pytest.mark.parametrize("reuse", ["fresh", "carry"])
+@pytest.mark.parametrize("granularity", [1, 16])
+def test_materialize_matches_gathered_mask_oracle(granularity, reuse,
+                                                  method, case):
+    """Selected.k/.v/.pos/.idx equal the mask-gathering oracle bit for bit,
+    and ``valid`` is the oracle's ``pos >= 0``.  A fresh plan reads its
+    validity off its indices; a carried plan consumed under a later layer's
+    view (new holes, an earlier chunk start) must re-derive it."""
+    b, t, h, n_kv, d = 2, 64, 4, 2, 16
+    key_pos, start, q_valid, keep_first = _views(case)
+    q = jax.random.normal(KEY, (b, 8, h, d))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (b, t, n_kv, d))
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (b, t, n_kv, d))
+    cfg = QuokaConfig(budget=32, n_queries=4, keep_first=keep_first,
+                      granularity=granularity, rank=8,
+                      reuse_interval=2 if reuse == "carry" else 1)
+    if reuse == "fresh":
+        pln = plan_mod.build(method, q, k, key_pos, start, cfg,
+                             q_valid=q_valid)
+        ref = _oracle_materialize(pln, k, v, key_pos, start, cfg)
+        _assert_same(plan_mod.select(method, q, k, v, key_pos, start, cfg,
+                                     q_valid=q_valid), ref)
+        sel, carry = plan_mod.select_with_ctx(
+            {"layer_idx": 0}, None, method, q, k, v, key_pos, start, cfg,
+            q_valid=q_valid)
+        assert carry is None
+        _assert_same(sel, ref)
+        return
+    carry = plan_mod.empty_carry(plan_mod.plan_idx_shape(cfg, b, n_kv, t))
+    sel0, carry = plan_mod.select_with_ctx(
+        {"layer_idx": 0}, carry, method, q, k, v, key_pos, start, cfg,
+        q_valid=q_valid)
+    _assert_same(sel0, _oracle_materialize(
+        plan_mod.SelectionPlan(idx=carry.idx), k, v, key_pos, start, cfg))
+    # layer 1 reuses layer 0's plan under a view with new holes and an
+    # earlier chunk start, so some carried slots are no longer selectable
+    pos1 = key_pos.at[:, 2:6].set(-1)
+    start1 = start - 8
+    sel1, carry1 = plan_mod.select_with_ctx(
+        {"layer_idx": 1}, carry, method, q, k, v, pos1, start1, cfg,
+        q_valid=q_valid)
+    np.testing.assert_array_equal(np.asarray(carry1.idx),
+                                  np.asarray(carry.idx))
+    ref = _oracle_materialize(plan_mod.SelectionPlan(idx=carry.idx), k, v,
+                              pos1, start1, cfg)
+    _assert_same(sel1, ref)
+    planned = int((carry.idx >= 0).sum()) * (
+        1 if granularity == 1 else granularity * n_kv)
+    assert int(ref.valid.sum()) < planned, "reuse case masks nothing"
 
 
 # ---------------------------------------------------------------------------
@@ -247,6 +392,56 @@ def test_materialize_hlo_contiguous_block_slices():
     assert payload, f"no KV-payload gather found: {sizes}"
     assert all(g in s for s in payload), \
         f"per-token gather on the KV payload: {sizes}"
+
+
+def _serving_step_texts(registry):
+    """Compiled HLO text of the engine's jitted (prefill, decode) steps for
+    the qwen3 smoke config with quoka at granularity 1.  Prompts exceed the
+    32-token budget so both steps select."""
+    from repro.serving.engine import Engine
+    from repro.serving.request import make_requests
+    cfg = get_config("qwen3-4b").smoke()
+    cfg = dataclasses.replace(cfg, quoka=dataclasses.replace(
+        cfg.quoka, granularity=1, budget=32, method="quoka"))
+    model = build_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), method="quoka",
+                 registry=registry)
+    reqs = make_requests([list(range(1, 90)), list(range(7, 60))], 3)
+    st = eng.make_serve_state(reqs, block_size=16, max_decode_batch=2)
+    args = {}
+
+    def capture(i):
+        def fn(*a):
+            args[i] = a
+            return st.fns[i](*a)
+        return fn
+
+    eng.serve(reqs, state=dataclasses.replace(
+        st, fns=(capture(0), capture(1))))
+    assert set(args) == {0, 1}, "a step program never ran"
+    d = cfg.resolved_head_dim
+    return d, [st.fns[i].lower(*args[i]).compile().as_text() for i in (0, 1)]
+
+
+@pytest.mark.parametrize("layer_obs", [False, True])
+def test_materialize_hlo_gathers_follow_reader(layer_obs):
+    """A fresh token plan materializes with two gathers, the K and V rows:
+    the cache view's validity is never gathered, and the positions gather
+    is dead code when nothing reads positions as values.  With LayerObs on
+    (a registry at construction) telemetry reads ``Selected.pos``, so a
+    positions gather is back — the route follows the reader."""
+    from repro.obs.registry import Registry
+    d, texts = _serving_step_texts(Registry() if layer_obs else None)
+    for text in texts:
+        sizes = hlo.gathers_in_scope(text, "plan_materialize")
+        assert sizes, "plan_materialize scope not visible in the HLO"
+        rows = [s for s in sizes if s[-1] == d]
+        scalar = [s for s in sizes if all(x == 1 for x in s)]
+        if layer_obs:
+            assert scalar, f"no positions gather with LayerObs on: {sizes}"
+        else:
+            assert len(sizes) == 2 and rows == sizes, sizes
+            assert not scalar, sizes
 
 
 def test_gather_blocks_hlo_contiguous_block_slices():
