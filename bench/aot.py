@@ -65,7 +65,7 @@ def main() -> int:
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
 
-    cfg = serve.model_config(cell.config)
+    cfg = serve.model_config(cell.config, cell.reference)
     from repro.models.model import build_model
     from repro.serving.engine import Engine
     model = build_model(cfg)

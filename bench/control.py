@@ -8,7 +8,9 @@
 
 For each seed: one run of the cell (``run.run_cell``, at the cell's own
 load and window, the same sample of finished requests a run compares) and
-its mean logit gap against the plain reference: the program's reading.
+its mean logit gap against the plain reference of the configuration's
+``model_type`` (``bench/reference/<model_type>.py``, the cell's
+``reference``): the program's reading.
 For the control seeds also the control's: at the same positions of the
 same prompts and served tokens, the mean gap of the token that the
 reference computed in float8 puts first, judged by the run's own

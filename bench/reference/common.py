@@ -1,20 +1,16 @@
-"""Plain reference of a served dense GQA configuration with QUOKA selection.
+"""What every plain reference shares: sizes, precision, QUOKA selection.
 
 Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no
 kernels, no paged cache, no batching.  It imports nothing of the program.
-One request at a time, the whole sequence (prompt, then every served token
-but the last) in one pass, and returns the logits at the positions that
-produced the served tokens.
+A reference module (``bench/reference/<model_type>.py``) runs one request
+at a time, the whole sequence (prompt, then every served token but the
+last) in one pass, and returns the logits at the positions that produced
+the served tokens.  Its layer is its own; the attention it calls is this
+module's ``attend``:
 
-What it computes, layer by layer (the program's architecture, departures
-from the published models listed in ``bench/configs/*.json``):
-
-    x   = emb[tokens]
-    h   = rmsnorm(x) ; q, k, v = h Wq, h Wk, h Wv ; rotary on q and k
-    att = softmax(q k^T / sqrt(head_dim)) v   over the keys each query may
-          see (below)
-    x  += att Wo ; x += W_down(silu(W_gate rmsnorm(x)) * W_up rmsnorm(x))
-    logits = rmsnorm(x) emb^T                    (tied read-out)
+    att = softmax(q k^T * scale) v   over the keys each query may see
+                                     (below); scale 1/sqrt(head_dim)
+                                     unless the spec sets ``attn_scale``
 
 Which keys a query sees.  The prompt is served in chunks of ``chunk``
 tokens from position 0, and every served token after the first is decoded
@@ -43,10 +39,11 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG = -1e30
@@ -70,13 +67,16 @@ class RefSpec:
     select_decode: bool
     t_max: int          # padded sequence length (a multiple of chunk)
     n_max: int          # most served tokens of one request
+    tied: bool          # read-out through emb^T, else through lm_head
     low: bool = False
     q_block: int = 512
+    attn_scale: Optional[float] = None    # softmax scale; 1/sqrt(head_dim)
 
 
 def spec_for(config: dict, t_max: int, n_max: int, capacity: int,
              low: bool = False) -> RefSpec:
-    """The reference's sizes for a configuration file and a geometry."""
+    """The reference's sizes for a configuration file and a geometry, from
+    the keys every dense GQA configuration publishes."""
     prog = config["program"]
     chunk = int(prog["chunk_size"])
     budget = min(int(prog["budget"]), capacity)
@@ -93,8 +93,22 @@ def spec_for(config: dict, t_max: int, n_max: int, capacity: int,
         keep_first=int(prog["keep_first"]),
         select_prefill=quoka and capacity > budget + chunk,
         select_decode=quoka and capacity > budget + 1,
-        t_max=t_max, n_max=int(n_max), low=low,
+        t_max=t_max, n_max=int(n_max),
+        tied=bool(config["tie_word_embeddings"]), low=low,
         q_block=min(512, t_max))
+
+
+def sequence(prompt, served, s: RefSpec):
+    """``(tokens, n_prompt, length)`` of one request for a jitted forward:
+    the prompt, then each served token but the last, padded to ``t_max``."""
+    seq = np.concatenate([np.asarray(prompt), np.asarray(served)[:-1]])
+    n = len(served)
+    if len(seq) > s.t_max or n > s.n_max:
+        raise ValueError(f"request of {len(prompt)} + {n} tokens exceeds the "
+                         f"reference's {s.t_max} positions / {s.n_max} served")
+    tokens = np.zeros((s.t_max,), np.int32)
+    tokens[:len(seq)] = seq
+    return jnp.asarray(tokens), jnp.int32(len(prompt)), jnp.int32(len(seq))
 
 
 def _fp8(x):
@@ -104,23 +118,27 @@ def _fp8(x):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(a, b, low):
+def mm(a, b, low):
+    """``a @ b`` at HIGHEST; with ``low`` on float8-rounded operands."""
     if low:
         a, b = _fp8(a), _fp8(b)
     return jnp.matmul(a, b, precision=HIGHEST)
 
 
-def _es(eq, a, b, low):
+def es(eq, a, b, low):
+    """``einsum(eq, a, b)`` at HIGHEST; with ``low`` on float8-rounded
+    operands."""
     if low:
         a, b = _fp8(a), _fp8(b)
     return jnp.einsum(eq, a, b, precision=HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
+    """Rotary embedding, the two halves of each head rotated together."""
     half = x.shape[-1] // 2
     freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
                     / half)
@@ -180,7 +198,7 @@ def _selection(q, k, n_prompt, starts, s: RefSpec):
     qd = _decode_qbar(q, n_prompt, s, qp.shape[1])
     qbar = jnp.concatenate([qp, qd], 0)
     kn = k * jax.lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-16)
-    sc = _es("unkd,tkd->uknt", qbar, kn, s.low).max(2)         # (u, kv, t)
+    sc = es("unkd,tkd->uknt", qbar, kn, s.low).max(2)          # (u, kv, t)
     sc = jnp.where(prior[:, None, :], sc, NEG)
     sink = prior & (t[None, :] < s.keep_first)
     sc = jnp.where(sink[:, None, :], jnp.inf, sc)
@@ -192,7 +210,10 @@ def _selection(q, k, n_prompt, starts, s: RefSpec):
     return jnp.where(on[:, None, None], chosen, every)
 
 
-def _attend(q, k, v, n_prompt, length, s: RefSpec):
+def attend(q, k, v, n_prompt, length, s: RefSpec):
+    """(t_max, n_heads, head_dim) attention of rotated ``q`` (t_max,
+    n_heads, head_dim) over ``k``, ``v`` (t_max, n_kv, head_dim), each
+    query over the keys it may see, in blocks of ``q_block`` queries."""
     t_max, c = s.t_max, s.chunk
     up = t_max // c
     g = s.n_heads // s.n_kv
@@ -202,7 +223,7 @@ def _attend(q, k, v, n_prompt, length, s: RefSpec):
                               n_prompt + jnp.arange(s.n_max - 1)])
     sel = _selection(q, k, n_prompt, starts, s)
     n_units = starts.shape[0]
-    scale = s.head_dim ** -0.5
+    scale = s.head_dim ** -0.5 if s.attn_scale is None else s.attn_scale
     out = []
     for b0 in range(0, t_max, s.q_block):
         rows = t[b0:b0 + s.q_block]
@@ -216,59 +237,9 @@ def _attend(q, k, v, n_prompt, length, s: RefSpec):
                             (t[None, :] == rows[:, None])[:, None, :],
                             allowed)
         qb = q[b0:b0 + s.q_block].reshape(-1, s.n_kv, g, s.head_dim)
-        lg = _es("rkgd,tkd->kgrt", qb, k, s.low) * scale
+        lg = es("rkgd,tkd->kgrt", qb, k, s.low) * scale
         lg = jnp.where(allowed.transpose(1, 0, 2)[:, None], lg, NEG)
         p = jax.nn.softmax(lg, axis=-1)
-        o = _es("kgrt,tkd->rkgd", p, v, s.low)
+        o = es("kgrt,tkd->rkgd", p, v, s.low)
         out.append(o.reshape(-1, s.n_heads, s.head_dim))
     return jnp.concatenate(out, 0)
-
-
-def _layer(x, p, n_prompt, length, s: RefSpec):
-    p = jax.tree.map(lambda a: a.astype(jnp.float32), p)
-    t = s.t_max
-    pos = jnp.arange(t)
-    h = _rms(x, p["ln1"]["g"], s.eps)
-    q = _mm(h, p["wq"]["w"], s.low).reshape(t, s.n_heads, s.head_dim)
-    k = _mm(h, p["wk"]["w"], s.low).reshape(t, s.n_kv, s.head_dim)
-    v = _mm(h, p["wv"]["w"], s.low).reshape(t, s.n_kv, s.head_dim)
-    q, k = _rope(q, pos, s.theta), _rope(k, pos, s.theta)
-    att = _attend(q, k, v, n_prompt, length, s)
-    x = x + _mm(att.reshape(t, -1), p["wo"]["w"], s.low)
-    h = _rms(x, p["ln2"]["g"], s.eps)
-    m = p["mlp"]
-    up = jax.nn.silu(_mm(h, m["gate"]["w"], s.low)) * _mm(h, m["up"]["w"],
-                                                          s.low)
-    return x + _mm(up, m["down"]["w"], s.low)
-
-
-@partial(jax.jit, static_argnums=(4,))
-def _forward(params, tokens, n_prompt, length, s: RefSpec):
-    emb = params["embed"]["emb"]
-    x = emb[tokens].astype(jnp.float32)
-    (layers,) = params["stacks"][0]
-
-    def body(x, p):
-        return _layer(x, p, n_prompt, length, s), None
-
-    x, _ = jax.lax.scan(body, x, layers)
-    x = _rms(x, params["ln_f"]["g"].astype(jnp.float32), s.eps)
-    rows = jnp.clip(n_prompt - 1 + jnp.arange(s.n_max), 0, s.t_max - 1)
-    return _mm(x[rows], emb.astype(jnp.float32).T, s.low)
-
-
-def logits(params, prompt, served, s: RefSpec):
-    """(len(served), vocab) float32 logits at the positions that produced
-    each served token: the last prompt position, then each served token
-    but the last."""
-    import numpy as np
-    seq = np.concatenate([np.asarray(prompt), np.asarray(served)[:-1]])
-    n = len(served)
-    if len(seq) > s.t_max or n > s.n_max:
-        raise ValueError(f"request of {len(prompt)} + {n} tokens exceeds the "
-                         f"reference's {s.t_max} positions / {s.n_max} served")
-    tokens = np.zeros((s.t_max,), np.int32)
-    tokens[:len(seq)] = seq
-    out = _forward(params, jnp.asarray(tokens), jnp.int32(len(prompt)),
-                   jnp.int32(len(seq)), s)
-    return out[:n]

@@ -17,10 +17,11 @@ A cell is one entry of ``BENCHMARK.json``'s ``workloads``: a configuration
            requests due in ``[0, seconds)``, each token stamped as it is
            yielded; cells that judge TTFT then drain what is left
   check    a sample of the finished requests, drawn from the seed with the
-           longest among them, against the plain reference
-           (``bench/reference.py``): by how much a served token's logit
-           lies below the reference's best one, averaged over the
-           sample's served tokens (``logit_gap_mean``)
+           longest among them, against the plain reference of the
+           configuration's ``model_type`` (``bench/reference/``): by how
+           much a served token's logit lies below the reference's best
+           one, averaged over the sample's served tokens
+           (``logit_gap_mean``)
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` reports
 the per-layer metrics (``bench/metrics/<name>.py``) with a breakdown.  A
@@ -165,18 +166,18 @@ def _sample(done, seed: int, check: dict):
     return out
 
 
-def compare(params, config, traffic, geo, sample, control: bool = False):
-    """Per served token, how far its logit lies below the plain
-    reference's best; with ``control`` also the gap of the token that the
-    control (the reference in float8) puts first.  Returns (program gaps,
-    control gaps)."""
+def compare(params, cell, geo, sample, control: bool = False):
+    """Per served token, how far its logit lies below the best of the plain
+    reference of the cell's ``model_type``; with ``control`` also the gap
+    of the token that the control (the reference in float8) puts first.
+    Returns (program gaps, control gaps)."""
     import jax
     import numpy as np
 
-    import reference as ref
-    p_max, o_max = traffic_mod.bounds(traffic)
-    rs = ref.spec_for(config, p_max + o_max, o_max, geo["capacity"])
-    cs = ref.spec_for(config, p_max + o_max, o_max, geo["capacity"],
+    ref = cell.reference
+    p_max, o_max = traffic_mod.bounds(cell.traffic)
+    rs = ref.spec_for(cell.config, p_max + o_max, o_max, geo["capacity"])
+    cs = ref.spec_for(cell.config, p_max + o_max, o_max, geo["capacity"],
                       low=True)
     gaps, ctrl = [], []
     for r in sample:
@@ -285,7 +286,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
 
     cfgf, tr = cell.config, cell.traffic
     prog = cfgf["program"]
-    mcfg = serve.model_config(cfgf)
+    mcfg = serve.model_config(cfgf, cell.reference)
     geo = serve.geometry(cfgf, tr)
     dims = flops_mod.Dims.from_config(cfgf, geo["capacity"])
     model = build_model(mcfg)
@@ -467,7 +468,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, peaks: dict,
         diag.update(view.notes)
         del replay
         gc.collect()
-    gaps, ctrl = compare(params, cfgf, tr, geo, sample, control=control)
+    gaps, ctrl = compare(params, cell, geo, sample, control=control)
     compared = judge(cell, gaps, failed)
     correct = all(c[3] for c in compared)
     diag.update(gap_stats("logit_gap", gaps))

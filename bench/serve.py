@@ -2,7 +2,9 @@
 
 ``model_config`` turns ``bench/configs/<name>.json`` (published keys under
 their published names, plus a ``program`` group) into the program's
-``ModelConfig``: the file, not the program's preset, sets every size.
+``ModelConfig``: the file, not the program's preset, sets every size, and
+the keys that the configuration's reference module maps in
+``PROGRAM_KEYS`` (``bench/reference/<model_type>.py``) set the rest.
 ``geometry`` fixes the compiled step geometry from the mix's declared
 maxima, so every seed of a cell hits the same compiled programs.
 """
@@ -26,11 +28,20 @@ _WIDTHS = {
 }
 
 
-def model_config(config: Dict):
+def model_config(config: Dict, reference=None):
+    """The program's ``ModelConfig`` for a configuration file; ``reference``
+    is the module of its ``model_type``, whose ``PROGRAM_KEYS`` map further
+    published keys to fields."""
     from repro.configs import get_config
     prog = config["program"]
     base = get_config(prog["arch"])
-    sizes = {field: config[key] for key, field in _WIDTHS.items()
+    keys = {**_WIDTHS, **getattr(reference, "PROGRAM_KEYS", {})}
+    fields = {f.name for f in dataclasses.fields(base)}
+    unknown = sorted(f for f in keys.values() if f not in fields)
+    if unknown:
+        raise ValueError(f"PROGRAM_KEYS of {config.get('model_type')!r} "
+                         f"name fields ModelConfig lacks: {unknown}")
+    sizes = {field: config[key] for key, field in keys.items()
              if key in config}
     if "head_dim" not in config:
         sizes["head_dim"] = config["hidden_size"] // \

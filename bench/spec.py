@@ -5,6 +5,8 @@ belongs to one configuration, one traffic mix, one cell's limits or one
 per-layer metric lives in a file of its own, found here by that name:
 
     bench/configs/<config>.json     sizes as run, source, cuts, departures
+    bench/reference/<model_type>.py the plain reference of the config's
+                                    ``model_type``
     bench/traffic/<traffic>.json    lengths, arrivals, rate, serving geometry
     bench/limits/<cell>.json        limits of the numbers that decide correct
     bench/metrics/<metric>.py       one reader per per-layer metric
@@ -14,8 +16,10 @@ entries; no file here needs an edit.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List, Optional
@@ -33,6 +37,13 @@ class Cell:
     chips: int
     end_to_end: List[Dict]          # the cell's end-to-end metric entries
     per_layer: List[Dict]           # the cell's per-layer metric entries
+    bench_dir: Path = BENCH_DIR     # where its files are found
+
+    @property
+    def reference(self):
+        """The plain reference module of the configuration's
+        ``model_type``."""
+        return load_reference(self.config["model_type"], self.bench_dir)
 
 
 def load_json(path: Path) -> Dict:
@@ -55,13 +66,35 @@ def load_cell(name: str, bench_dir: Path = BENCH_DIR,
                        f"{sorted(cells)}")
     w = cells[name]
     cfg = load_json(bench_dir / "configs" / f"{w['config']}.json")
+    if "model_type" not in cfg:
+        raise KeyError(f"configs/{w['config']}.json names no model_type")
+    load_reference(cfg["model_type"], bench_dir)      # raises where none
     tr = load_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     lim_path = bench_dir / "limits" / f"{name}.json"
     limits = load_json(lim_path) if lim_path.exists() else {}
     e2e = [m for m in benchmark["end_to_end"] if _applies(m, name)]
     per = [m for m in benchmark["per_layer"] if _applies(m, name)]
     return Cell(name=name, config=cfg, traffic=tr, limits=limits,
-                chips=int(w["chips"]), end_to_end=e2e, per_layer=per)
+                chips=int(w["chips"]), end_to_end=e2e, per_layer=per,
+                bench_dir=bench_dir)
+
+
+@functools.cache
+def load_reference(model_type: str, bench_dir: Path = BENCH_DIR):
+    """The module ``bench/reference/<model_type>.py``, loaded once: a
+    configuration is compared only against its own architecture's
+    maths."""
+    path = bench_dir / "reference" / f"{model_type}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"model_type {model_type!r} has no plain "
+                                f"reference at {path}")
+    name = f"bench_reference_{model_type}"
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    # a dataclass resolves its annotations through sys.modules
+    sys.modules[name] = mod
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def load_metric_reader(name: str, bench_dir: Path = BENCH_DIR):

@@ -257,7 +257,10 @@ def test_new_files_are_found_by_name(tmp_path):
     (tmp_path / "traffic").mkdir()
     (tmp_path / "metrics").mkdir()
     (tmp_path / "limits").mkdir()
-    (tmp_path / "configs" / "m.json").write_text('{"hidden_size": 8}')
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "configs" / "m.json").write_text(
+        '{"model_type": "tiny", "hidden_size": 8}')
+    (tmp_path / "reference" / "tiny.py").write_text("ARCH = 'tiny'\n")
     (tmp_path / "traffic" / "t.json").write_text('{"rate_per_s": 1}')
     (tmp_path / "limits" / "m.t.json").write_text(
         '{"logit_gap_mean": {"limit": 1}}')
@@ -272,11 +275,83 @@ def test_new_files_are_found_by_name(tmp_path):
           "per_layer": [{"name": "x.y_ms", "unit": "ms",
                          "workloads": ["m.t"]}]}
     cell = spec.load_cell("m.t", bench_dir=tmp_path, benchmark=bm)
-    assert cell.config == {"hidden_size": 8}
+    assert cell.config == {"model_type": "tiny", "hidden_size": 8}
+    assert cell.reference.ARCH == "tiny"
     assert cell.traffic == {"rate_per_s": 1}
     assert cell.limits["logit_gap_mean"]["limit"] == 1
     assert [m["name"] for m in cell.end_to_end] == ["setup_s"]
     assert spec.load_metric_reader("x.y_ms", bench_dir=tmp_path)(21) == 42
+
+
+_FAKE_REFERENCE = """
+import numpy as np
+
+PROGRAM_KEYS = {"sliding_window": "sliding_window"}
+
+
+def spec_for(config, t_max, n_max, capacity, low=False):
+    return low
+
+
+def logits(params, prompt, served, s):
+    lg = np.zeros((len(served), 8))
+    lg[:, 0] = 1.0
+    return lg
+"""
+
+
+def _arch_bench(tmp_path, model_type, **keys):
+    """A bench directory in ``tmp_path`` with one cell, ``m.long_prefill``,
+    of qwen3-4b's sizes under ``model_type``, and its BENCHMARK entries."""
+    for d in ("configs", "traffic", "reference"):
+        (tmp_path / d).mkdir()
+    cfg = json.loads((BENCH / "configs" / "qwen3-4b.json").read_text())
+    cfg.update(model_type=model_type, **keys)
+    (tmp_path / "configs" / "m.json").write_text(json.dumps(cfg))
+    (tmp_path / "traffic" / "long_prefill.json").write_text(
+        (BENCH / "traffic" / "long_prefill.json").read_text())
+    return {"workloads": [{"name": "m.long_prefill", "config": "m",
+                           "traffic": "long_prefill", "chips": 1,
+                           "why": "w"}],
+            "end_to_end": [], "per_layer": []}
+
+
+def test_a_new_architecture_is_found_and_applied_by_adding_files(tmp_path):
+    import run
+    import serve
+    from types import SimpleNamespace
+    bm = _arch_bench(tmp_path, "newarch", sliding_window=96)
+    path = tmp_path / "reference" / "newarch.py"
+    path.write_text(_FAKE_REFERENCE)
+    cell = spec.load_cell("m.long_prefill", bench_dir=tmp_path, benchmark=bm)
+    assert cell.reference.__file__ == str(path)
+    # its PROGRAM_KEYS reach the program's ModelConfig beside the widths
+    mcfg = serve.model_config(cell.config, cell.reference)
+    assert mcfg.sliding_window == 96 and mcfg.d_model == 2560
+    # and the comparison computes the reference with its maths: token 0
+    # is its best, so a served 0 reads 0 below it and any other 1
+    sample = [SimpleNamespace(tokens=[5, 6, 7], out=[0, 3, 0])]
+    gaps, ctrl = run.compare(None, cell, {"capacity": 64}, sample,
+                             control=True)
+    assert gaps.tolist() == [0.0, 1.0, 0.0] and ctrl.tolist() == [0.0] * 3
+
+
+def test_a_program_key_that_model_config_lacks_raises():
+    import serve
+    from types import SimpleNamespace
+    cfg = json.loads((BENCH / "configs" / "qwen3-4b.json").read_text())
+    bad = SimpleNamespace(PROGRAM_KEYS={"attention_multiplier":
+                                        "attention_multiplier"})
+    with pytest.raises(ValueError, match="attention_multiplier"):
+        serve.model_config(cfg, bad)
+
+
+def test_a_model_type_without_a_reference_raises_with_its_path(tmp_path):
+    bm = _arch_bench(tmp_path, "nosucharch")
+    with pytest.raises(FileNotFoundError,
+                       match=re.escape(str(tmp_path / "reference"
+                                           / "nosucharch.py"))):
+        spec.load_cell("m.long_prefill", bench_dir=tmp_path, benchmark=bm)
 
 
 # ---------------------------------------------------------------------------
@@ -312,10 +387,11 @@ def _tiny_cell():
 
 
 def _run(fault=None, control=False, trace=False, seed=2 ** 31 + 11,
-         rate=None, check=None):
+         rate=None, check=None, **config):
     import run
     peaks = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
     cell = _tiny_cell()
+    cell.config.update(config)
     if check:
         cell.traffic["check"] = check
     return run.run_cell(cell, seed, 2.0, trace, peaks=peaks,
@@ -344,6 +420,14 @@ def test_control_reads_far_above_the_program(sound):
     assert prog < TINY_LIMIT < ctrl and ctrl >= 3 * prog
     # the control in the program's place fails the run's own comparison
     assert sound["diag"]["control_correct"] is False
+
+
+def test_untied_readout_is_compared_through_lm_head():
+    # the program reads out through params["lm_head"]; a reference that
+    # read through emb^T would put other tokens first
+    res = _run(seed=17, tie_word_embeddings=False)
+    assert res["line"]["correct"] is True
+    assert float(res["gaps"].mean()) < TINY_LIMIT
 
 
 def test_traced_run_reports_per_layer_metrics():
